@@ -17,7 +17,7 @@ import struct
 import numpy as np
 import jax.numpy as jnp
 
-from presto_tpu.apps.common import load_spectrum, load_timeseries, ensure_backend
+from presto_tpu.apps.common import load_spectrum, load_timeseries
 from presto_tpu.ops import fftpack
 from presto_tpu.ops.rednoise import (deredden, read_birds_bary, zap_bins,
                                      birds_to_bin_ranges)
@@ -185,35 +185,22 @@ def refine_and_write(raw_cands, amps, T, searcher, base, zmax,
     use_batch = (os.environ.get("PRESTO_TPU_POLISH", "batch")
                  != "scipy")
     if use_batch and cands:
-        try:
-            from presto_tpu.search.polish import optimize_accelcands
-            ocs = optimize_accelcands(amps, cands, T,
-                                      searcher.numindep,
-                                      harmpolish=harmpolish,
-                                      with_props=False)
-        except Exception as e:
-            print("accelsearch: batched polish failed (%s); "
-                  "using the per-candidate path" % (e,))
-            ocs = [None] * len(cands)
+        from presto_tpu.search.polish import optimize_accelcands
+        ocs = optimize_accelcands(amps, cands, T, searcher.numindep,
+                                  harmpolish=harmpolish,
+                                  with_props=False)
     if use_batch and cands and wmax and all(o is not None
                                             for o in ocs):
         # batched (r, z, w) jerk polish seeded from the z-polish (the
         # per-candidate max_rzw_arr path rebuilds a w-response
         # quadrature per power evaluation: minutes per candidate)
-        try:
-            from presto_tpu.search.accel import AccelCand
-            from presto_tpu.search.polish import optimize_jerk_cands
-            seeds = [AccelCand(power=o.power, sigma=o.sigma,
-                               numharm=o.numharm, r=o.r, z=o.z,
-                               w=c.w)
-                     for c, o in zip(cands, ocs)]
-            jocs = optimize_jerk_cands(amps, seeds, T,
-                                       searcher.numindep,
-                                       harmpolish=harmpolish)
-        except Exception as e:
-            print("accelsearch: batched jerk polish failed (%s); "
-                  "using the per-candidate path" % (e,))
-            jocs = [None] * len(cands)
+        from presto_tpu.search.accel import AccelCand
+        from presto_tpu.search.polish import optimize_jerk_cands
+        seeds = [AccelCand(power=o.power, sigma=o.sigma,
+                           numharm=o.numharm, r=o.r, z=o.z, w=c.w)
+                 for c, o in zip(cands, ocs)]
+        jocs = optimize_jerk_cands(amps, seeds, T, searcher.numindep,
+                                   harmpolish=harmpolish)
     refined = []
     for c, oc, joc in zip(cands, ocs, jocs):
         try:
@@ -268,7 +255,6 @@ def refine_and_write(raw_cands, amps, T, searcher, base, zmax,
 
 
 def run(args):
-    ensure_backend()
     base, ext = os.path.splitext(args.infile)
     if ext == ".dat" or (not os.path.exists(base + ".fft")
                          and os.path.exists(base + ".dat")):

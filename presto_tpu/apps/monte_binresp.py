@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import argparse
 
-from presto_tpu.apps.common import ensure_backend
 from presto_tpu.pipeline.monte import (MonteConfig, format_table,
                                        run_campaign, save_json)
 
@@ -43,7 +42,6 @@ def build_parser():
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
-    ensure_backend()
     cfg = MonteConfig(N=args.N, dt=args.dt, f_psr=args.fpsr,
                       amp=args.amp, asini_lts=args.asini,
                       ecc=args.ecc, pb_over_t=tuple(args.ratios),

@@ -23,7 +23,11 @@ def build_parser():
     p.add_argument("-sigma", type=float, default=4.0)
     p.add_argument("-rfitime", type=float, default=2.0)
     p.add_argument("-zaplist", type=str, default=None)
-    p.add_argument("-foldtop", type=int, default=3)
+    p.add_argument("-foldtop", type=int, default=None,
+                   help="fold the top N candidates (default 3); with "
+                        "--recipe, "
+                        "caps the recipe's fold budget (per pass "
+                        "where the recipe splits it)")
     p.add_argument("-nosp", action="store_true",
                    help="Skip the single-pulse search stage")
     p.add_argument("-norfi", action="store_true",
@@ -68,8 +72,7 @@ def main(argv=None) -> int:
     if args.recipe:
         # the recipe OWNS these policies — explicitly-passed values
         # would be silently ignored, so make the conflict loud
-        for name in ("zmax", "numharm", "sigma", "rfitime",
-                     "foldtop"):
+        for name in ("zmax", "numharm", "sigma", "rfitime"):
             if getattr(args, name) != parser.get_default(name):
                 raise SystemExit(
                     "pipeline: -%s conflicts with --recipe %s (the "
@@ -81,12 +84,19 @@ def main(argv=None) -> int:
             zaplist=args.zaplist)
         cfg.singlepulse = not args.nosp
         cfg.skip_rfifind = args.norfi
+        if args.foldtop is not None:
+            # an explicit -foldtop caps the recipe's fold budget
+            cfg.max_folds = min(cfg.max_folds, args.foldtop)
+            if cfg.max_folds_per_pass:
+                cfg.max_folds_per_pass = tuple(
+                    min(c, args.foldtop) for c in cfg.max_folds_per_pass)
     else:
         cfg = SurveyConfig(
             lodm=args.lodm, hidm=args.hidm, nsub=args.nsub,
             zmax=args.zmax, numharm=args.numharm, sigma=args.sigma,
             rfi_time=args.rfitime, zaplist=args.zaplist,
-            fold_top=args.foldtop, singlepulse=not args.nosp,
+            fold_top=3 if args.foldtop is None else args.foldtop,
+            singlepulse=not args.nosp,
             skip_rfifind=args.norfi)
     if args.triage:
         cfg.triage = {"budget": args.triage_budget,

@@ -10,7 +10,6 @@ import sys
 
 import numpy as np
 
-from presto_tpu.apps.common import ensure_backend
 from presto_tpu.io import datfft
 from presto_tpu.io.infodata import read_inf
 from presto_tpu.ops import fftpack
@@ -22,7 +21,6 @@ def main(argv=None) -> int:
                    help="Number of top peaks to list")
     p.add_argument("datafile")
     args = p.parse_args(argv)
-    ensure_backend()
     base, ext = os.path.splitext(args.datafile)
     if ext == ".dat":
         data = datfft.read_dat(args.datafile)

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from presto_tpu.io import datfft
 from presto_tpu.io.infodata import read_inf, write_inf
 from presto_tpu.ops import fftpack
-from presto_tpu.apps.common import ensure_backend
 
 
 def build_parser():
@@ -118,7 +117,6 @@ def run_one(path: str, forward: bool, delete: bool,
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
-    ensure_backend()
     for path in args.datafiles:
         ext = os.path.splitext(path)[1]
         forward = args.fwd or (ext == ".dat" and not args.inv)

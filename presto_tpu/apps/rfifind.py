@@ -19,7 +19,7 @@ import numpy as np
 
 from presto_tpu.apps.common import (add_common_flags, add_raw_flags,
                                     open_raw_args, BlockPrep,
-                                    fil_to_inf, ensure_backend, obs_metadata)
+                                    fil_to_inf, obs_metadata)
 from presto_tpu.io.infodata import write_inf, read_inf
 from presto_tpu.io.maskfile import (read_mask, read_statsfile,
                                     determine_padvals)
@@ -117,7 +117,6 @@ def _run_nocompute(args):
 
 
 def run(args):
-    ensure_backend()
     if args.nocompute:
         return _run_nocompute(args)
     if not args.rawfiles:

@@ -12,7 +12,7 @@ import sys
 
 import numpy as np
 
-from presto_tpu.apps.common import ensure_backend, load_spectrum
+from presto_tpu.apps.common import load_spectrum
 from presto_tpu.search.phasemod import (PhaseModConfig, search_phasemod,
                                         write_bincands, rawbin_report)
 
@@ -43,7 +43,6 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def run(args):
-    ensure_backend()
     if args.stack > 0:
         # stacked mode: the file holds pre-summed float32 POWERS, not
         # complex amplitudes (search_bin.c:243-246 read_float_file)

@@ -19,7 +19,6 @@ from __future__ import annotations
 import argparse
 import sys
 
-from presto_tpu.apps.common import ensure_backend
 
 
 def build_parser():
@@ -104,7 +103,6 @@ def main(argv=None) -> int:
     import signal
     import threading
     args = build_parser().parse_args(argv)
-    ensure_backend()
     from presto_tpu.obs import ObsConfig
     from presto_tpu.serve.scheduler import SchedulerConfig
     from presto_tpu.serve.server import SearchService, start_http

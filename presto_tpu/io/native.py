@@ -4,59 +4,77 @@ The reference keeps its raw-data path in C (INSTRUMENTOBJS: bit-unpack
 psrfits.c:828-866, scale/offset/weight psrfits.c:805-814, the
 get_rawblock readers behind backend_common.h:86-87).  This module loads
 the TPU-era equivalent — fused decode kernels + a pthread prefetching
-block feeder — and silently falls back to pure NumPy when the shared
-library is absent or `PRESTO_TPU_NO_NATIVE=1`.
+block feeder — and falls back to pure NumPy when the shared
+library cannot be built or `PRESTO_TPU_NO_NATIVE=1`.  A failed build
+warns once and leaves its reason in ``build_error``.
 
-The library is auto-built with `make -C csrc` on first import when a
-compiler is available; every entry point here is exercised against the
-NumPy reference path in tests/test_native.py.
+The library is built with `make -C csrc` on first use, into a file
+named by a hash of the committed sources (``library_path``); every
+entry point here is exercised against the NumPy reference path in
+tests/test_native.py.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import warnings
 from typing import Iterator, Optional
 
 import numpy as np
 
 _CSRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "csrc")
-_SO = os.path.join(_CSRC, "libpresto_tpu_io.so")
+_SOURCES = ("native_io.cpp", "Makefile")
 
 _lib = None
-_load_failed = False
+#: why the native library is unavailable (None while it loads or
+#: before the first try); callers that must not run on the NumPy
+#: fallback (chip_smoke.py) raise with it
+build_error: Optional[str] = None
 
 
-def _try_build() -> None:
-    src = os.path.join(_CSRC, "native_io.cpp")
-    if not os.path.exists(src):
-        return
-    if (os.path.exists(_SO)
-            and os.path.getmtime(_SO) >= os.path.getmtime(src)):
-        return
-    try:
-        subprocess.run(["make", "-C", _CSRC], check=True,
-                       capture_output=True, timeout=120)
-    except Exception:
-        pass
+def library_path() -> str:
+    """The build product for the committed sources: keyed on their
+    CONTENT, so a library copied along with a tree (or left from an
+    older source) is never loaded in place of what git holds."""
+    h = hashlib.sha256()
+    for name in _SOURCES:
+        with open(os.path.join(_CSRC, name), "rb") as f:
+            h.update(f.read())
+    return os.path.join(_CSRC, "libpresto_tpu_io.%s.so"
+                        % h.hexdigest()[:16])
+
+
+def _build(so: str) -> None:
+    """make into a private name, then rename: concurrent builders
+    (xdist workers, replicas) never load a half-written library."""
+    tmp = "%s.%d.tmp" % (so, os.getpid())
+    subprocess.run(["make", "-C", _CSRC, "TARGET=" + os.path.basename(tmp)],
+                   check=True, capture_output=True, text=True,
+                   timeout=300)
+    os.replace(tmp, so)
 
 
 def _load():
-    global _lib, _load_failed
+    global _lib, build_error
     if _lib is not None:
         return _lib
-    if _load_failed or os.environ.get("PRESTO_TPU_NO_NATIVE"):
-        return None
-    _try_build()
-    if not os.path.exists(_SO):
-        _load_failed = True      # don't re-spawn make per decode call
+    if build_error is not None or os.environ.get("PRESTO_TPU_NO_NATIVE"):
         return None
     try:
-        lib = ctypes.CDLL(_SO)
-    except OSError:
-        _load_failed = True
+        so = library_path()
+        if not os.path.exists(so):
+            _build(so)
+        lib = ctypes.CDLL(so)
+    except (OSError, subprocess.SubprocessError) as e:
+        err = getattr(e, "stderr", None) or ""
+        build_error = ("%s: %s %s" % (type(e).__name__, e, err)).strip()
+        warnings.warn("native IO library unavailable, decoding with "
+                      "NumPy: " + build_error, RuntimeWarning,
+                      stacklevel=3)
         return None
     u8p = ctypes.POINTER(ctypes.c_uint8)
     f32p = ctypes.POINTER(ctypes.c_float)
@@ -73,13 +91,7 @@ def _load():
     lib.pt_feeder_next.argtypes = [ctypes.c_void_p, u8p]
     lib.pt_feeder_next.restype = i64
     lib.pt_feeder_close.argtypes = [ctypes.c_void_p]
-    try:
-        # added after the first shipped .so: a stale library without
-        # the symbol still serves every older entry point
-        lib.pt_feeder_stats.argtypes = [ctypes.c_void_p,
-                                        ctypes.POINTER(i64)]
-    except AttributeError:
-        pass
+    lib.pt_feeder_stats.argtypes = [ctypes.c_void_p, ctypes.POINTER(i64)]
     _lib = lib
     return lib
 
@@ -205,7 +217,7 @@ class BlockFeeder:
         each side of the ring waited on the other (consumer_waits ->
         disk-bound, producer_waits -> compute-bound).  None when the
         loaded library predates the symbol."""
-        if not self._h or not hasattr(self._lib, "pt_feeder_stats"):
+        if not self._h:
             return None
         out = (ctypes.c_int64 * 3)()
         self._lib.pt_feeder_stats(self._h, out)

@@ -125,16 +125,20 @@ def inject_pulsar(data: np.ndarray, dt: float, freqs: np.ndarray,
                               abs(chanwidth), dt)
     delays = delay_from_dm(params.dm, np.asarray(freqs, float))
     delays = delays - delays.min()
-    t = start_sec + (np.arange(N) + 0.5) * dt
     out = data.copy()
-    for c in range(nchan):
-        tc = t - delays[c]
+    chans = np.arange(nchan)
+    amp_profs = (params.amp * profs).astype(np.float32)
+    # all channels at once, in row chunks of ~2^20 cells
+    rows = max(1, (1 << 20) // max(nchan, 1))
+    for r0 in range(0, N, rows):
+        t = start_sec + (np.arange(r0, min(r0 + rows, N)) + 0.5) * dt
+        tc = t[:, None] - delays[None, :]
         if params.orbit is not None:
             tc = tc - np.asarray(orbit_delays(tc, params.orbit))
         ph = (params.phase0 + params.f * tc
               + 0.5 * params.fdot * tc * tc)
         idx = np.mod((ph % 1.0) * _NFINE, _NFINE).astype(np.int64)
-        out[:, c] += (params.amp * profs[c, idx]).astype(np.float32)
+        out[r0:r0 + len(t)] += amp_profs[chans, idx]
     return out
 
 

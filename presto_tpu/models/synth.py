@@ -14,6 +14,7 @@ a hot path).
 
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass
 from typing import Optional
 
@@ -74,6 +75,24 @@ def fake_timeseries(N: int, dt: float, signal: FakeSignal,
     return data.astype(np.float32)
 
 
+def _channel_delays(nchan: int, lofreq: float, chanwidth: float,
+                    dm: float) -> np.ndarray:
+    """Per-channel dispersive delays (s), ascending channels, the
+    highest channel at zero — the dedispersion ops' reference."""
+    freqs = lofreq + np.arange(nchan) * chanwidth
+    delays = delay_from_dm(dm, freqs)
+    return delays - delays.min()
+
+
+def _signal_into(out: np.ndarray, t: np.ndarray, delays: np.ndarray,
+                 signal: FakeSignal) -> None:
+    """out[:, c] = the pulsar's amplitude at t - delays[c]."""
+    for c in range(out.shape[1]):
+        ph = signal.phase(t - delays[c])
+        out[:, c] = signal.amp * pulse_shape(ph, signal.shape,
+                                             signal.width)
+
+
 def fake_filterbank_data(N: int, dt: float, nchan: int, lofreq: float,
                          chanwidth: float, signal: FakeSignal,
                          noise_sigma: float = 0.0,
@@ -83,19 +102,32 @@ def fake_filterbank_data(N: int, dt: float, nchan: int, lofreq: float,
     arriving later in lower-frequency channels per the cold-plasma delay
     (delay_from_dm).  The highest channel has zero extra delay offset —
     matching how dedispersion references delays to the band."""
-    freqs = lofreq + np.arange(nchan) * chanwidth
-    delays = delay_from_dm(signal.dm, freqs)
-    delays = delays - delays.min()       # highest channel ~ zero delay
+    delays = _channel_delays(nchan, lofreq, chanwidth, signal.dm)
     t = (np.arange(N) + 0.5) * dt
     out = np.empty((N, nchan), dtype=np.float32)
-    for c in range(nchan):
-        ph = signal.phase(t - delays[c])
-        out[:, c] = signal.amp * pulse_shape(ph, signal.shape, signal.width)
+    _signal_into(out, t, delays, signal)
     out += baseline
     if noise_sigma > 0:
         rng = np.random.default_rng(seed)
         out += rng.normal(0.0, noise_sigma, out.shape).astype(np.float32)
     return out
+
+
+def _beam_header(path: str, dt: float, nchan: int, lofreq: float,
+                 chanwidth: float, nbits: int,
+                 tstart_mjd: float) -> FilterbankHeader:
+    return FilterbankHeader(
+        # GBT + a real sky position (the Crab) so the default
+        # barycentering path in the prep tools is exercised end-to-end
+        source_name="FAKEPSR", machine_id=10, telescope_id=6,
+        src_raj=53431.97, src_dej=220052.1,
+        fch1=lofreq + (nchan - 1) * chanwidth, foff=-chanwidth,
+        nchans=nchan, nbits=nbits, tstart=tstart_mjd, tsamp=dt, nifs=1,
+        rawdatafile=path.split("/")[-1])
+
+
+def _quantize8(data: np.ndarray) -> np.ndarray:
+    return np.clip(np.round(data * 4.0), 0, 255).astype(np.uint8)
 
 
 def fake_filterbank_file(path: str, N: int, dt: float, nchan: int,
@@ -107,22 +139,81 @@ def fake_filterbank_file(path: str, N: int, dt: float, nchan: int,
     data = fake_filterbank_data(N, dt, nchan, lofreq, chanwidth, signal,
                                 noise_sigma, baseline=32.0, seed=seed)
     if nbits == 8:
-        q = np.clip(np.round(data * 4.0), 0, 255).astype(np.uint8)
+        q = _quantize8(data)
     elif nbits == 32:
         q = data
     else:
         maxv = (1 << nbits) - 1
         q = np.clip(np.round(data * maxv / data.max()), 0, maxv).astype(
             np.uint16 if nbits == 16 else np.uint8)
-    hdr = FilterbankHeader(
-        # GBT + a real sky position (the Crab) so the default
-        # barycentering path in the prep tools is exercised end-to-end
-        source_name="FAKEPSR", machine_id=10, telescope_id=6,
-        src_raj=53431.97, src_dej=220052.1,
-        fch1=lofreq + (nchan - 1) * chanwidth, foff=-chanwidth,
-        nchans=nchan, nbits=nbits, tstart=tstart_mjd, tsamp=dt, nifs=1,
-        rawdatafile=path.split("/")[-1])
+    hdr = _beam_header(path, dt, nchan, lofreq, chanwidth, nbits,
+                       tstart_mjd)
     write_filterbank(path, hdr, q)
+    return hdr
+
+
+def write_beam(path: str, N: int, dt: float, nchan: int,
+               lofreq: float, chanwidth: float,
+               signal: Optional[FakeSignal] = None,
+               noise_sigma: float = 0.0, tstart_mjd: float = 59000.0,
+               seed: Optional[int] = 42, inject=None,
+               block: int = 1 << 15) -> FilterbankHeader:
+    """An 8-bit beam like fake_filterbank_file's (same header, baseline
+    and quantization) in constant memory, ``block`` spectra at a time
+    (~block * nchan * 16 bytes of host memory for any length).
+
+    Each block draws its noise from its own stream, spawned from
+    ``seed`` (numpy SeedSequence), so blocks are made in parallel on
+    every core — a 2 GiB beam in seconds rather than minutes.  The
+    bytes are therefore a function of (seed, block), and differ from
+    fake_filterbank_file's single whole-array stream.
+
+    ``inject``: models/inject.InjectParams of one pulsar added to every
+    block by inject.inject_pulsar (its smeared, per-channel model) after
+    the noise and before quantization; ``signal`` is synth's own closed
+    form (None: noise only)."""
+    from collections import deque
+    from concurrent.futures import ThreadPoolExecutor
+
+    from presto_tpu.io.atomic import atomic_open
+    from presto_tpu.io.sigproc import write_filterbank_header
+    from presto_tpu.models.inject import inject_pulsar
+    hdr = _beam_header(path, dt, nchan, lofreq, chanwidth, 8, tstart_mjd)
+    starts = range(0, N, block)
+    streams = np.random.SeedSequence(seed).spawn(len(starts))
+    delays = (_channel_delays(nchan, lofreq, chanwidth, signal.dm)
+              if signal is not None else None)
+    freqs = lofreq + np.arange(nchan) * chanwidth
+
+    def make(s0: int, stream) -> bytes:
+        n = min(block, N - s0)
+        out = np.full((n, nchan), 32.0, np.float32)
+        if signal is not None:
+            sig = np.empty_like(out)
+            _signal_into(sig, (np.arange(s0, s0 + n) + 0.5) * dt,
+                         delays, signal)
+            out += sig
+        if noise_sigma > 0:
+            noise = np.random.default_rng(stream).standard_normal(
+                (n, nchan), dtype=np.float32)
+            noise *= np.float32(noise_sigma)
+            out += noise
+        if inject is not None:
+            out = inject_pulsar(out, dt, freqs, inject,
+                                start_sec=s0 * dt)
+        return _quantize8(out)[:, ::-1].tobytes()   # foff < 0 on disk
+
+    nwork = os.cpu_count() or 1
+    with atomic_open(path, "wb") as f, \
+            ThreadPoolExecutor(nwork) as pool:
+        write_filterbank_header(hdr, f)
+        pending = deque()
+        for s0, stream in zip(starts, streams):
+            pending.append(pool.submit(make, s0, stream))
+            if len(pending) > nwork + 1:
+                f.write(pending.popleft().result())
+        while pending:
+            f.write(pending.popleft().result())
     return hdr
 
 

@@ -27,15 +27,6 @@ from presto_tpu.ops.dedispersion import (dedisp_subbands_block,
 from presto_tpu.parallel.mesh import (dm_sharding, replicated,
                                       shard_row_ranges)
 
-# jax.shard_map moved in/out of the top-level namespace across jax
-# releases (top-level in >=0.5/0.7, jax.experimental.shard_map before);
-# resolve once so the sharded paths run on whichever is installed.
-try:
-    _shard_map = jax.shard_map            # newer jax
-except AttributeError:                     # 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
 def shard_dm_array(arr, mesh: Mesh):
     """Place [numdms, ...] array with the DM axis across mesh 'dm'."""
     return jax.device_put(arr, dm_sharding(mesh, np.ndim(arr)))
@@ -241,6 +232,39 @@ def make_sharded_sixstep_fft(mesh: Mesh, rows: int):
 # ----------------------------------------------------------------------
 
 
+def compact_search_fn(searcher, mesh: Mesh, g, scanner, shape,
+                      compact_m: int):
+    """The jitted per-shard program of sharded_accel_search_many: the
+    fused build+scan of each local trial, compacted on-shard, over a
+    [numdms, numbins, 2] batch of ``shape``.  Cached on the searcher
+    (jax.jit caches on function identity; a fresh closure per call
+    would re-trace the fused build+scan every survey group)."""
+    from presto_tpu.search.accel import compact_scan_packed
+    axis = mesh.axis_names[0]
+    fkey = ("sharded_search_c", mesh, g.key, scanner, tuple(shape),
+            compact_m)
+    fn = searcher._fn_cache.get(fkey)
+    if fn is None:
+        build_body, scan_body = g.build_body, scanner.body
+
+        def per_shard(local, kern, sc):
+            def per_dm(_, x):
+                packed = scan_body(build_body(x, kern), sc)
+                return None, compact_scan_packed(packed, compact_m)
+            _, comp = jax.lax.scan(per_dm, None, local)
+            return comp                      # [nd_loc, 3, m]
+
+        # check_vma off: the Pallas builder and reducer declare their
+        # outputs without mesh-axis variance, which shard_map's check
+        # refuses on the chip
+        fn = jax.jit(jax.shard_map(
+            per_shard, mesh=mesh,
+            in_specs=(P(axis), P(), P()),
+            out_specs=P(axis), check_vma=False))
+        searcher._fn_cache[fkey] = fn
+    return fn
+
+
 def sharded_accel_search_many(searcher, pairs_batch, mesh: Mesh,
                               slab: int = 1 << 20,
                               compact_m: int = None, obs=None):
@@ -299,27 +323,8 @@ def sharded_accel_search_many(searcher, pairs_batch, mesh: Mesh,
         batch = xp.concatenate([batch] + [batch[-1:]] * pad)
     scols = jnp.asarray(np.asarray(start_cols, np.int32))
 
-    # cache the compiled programs on the searcher (jax.jit caches on
-    # function identity; a fresh closure per call would re-trace the
-    # fused build+scan every survey group)
-    from presto_tpu.search.accel import compact_scan_packed
-
-    fkey = ("sharded_search_c", mesh, g.key, slab_, k, batch.shape,
-            compact_m)
-    fn = searcher._fn_cache.get(fkey)
-    if fn is None:
-        def per_shard(local, kern, sc):
-            def per_dm(_, x):
-                packed = scan_body(build_body(x, kern), sc)
-                return None, compact_scan_packed(packed, compact_m)
-            _, comp = jax.lax.scan(per_dm, None, local)
-            return comp                      # [nd_loc, 3, m]
-
-        fn = jax.jit(_shard_map(
-            per_shard, mesh=mesh,
-            in_specs=(P(axis), P(), P()),
-            out_specs=P(axis)))
-        searcher._fn_cache[fkey] = fn
+    fn = compact_search_fn(searcher, mesh, g, scanner, batch.shape,
+                           compact_m)
     if obs is not None:
         from presto_tpu.obs import costmodel
         costmodel.probe(obs, "accel_search", fn, jnp.asarray(batch),
@@ -345,10 +350,10 @@ def sharded_accel_search_many(searcher, pairs_batch, mesh: Mesh,
                                 build_body(x, kern), sc)
                         _, packed = jax.lax.scan(per_dm, None, local)
                         return jnp.moveaxis(packed, 1, 0)
-                    dfn = jax.jit(_shard_map(
+                    dfn = jax.jit(jax.shard_map(
                         per_shard_dense, mesh=mesh,
                         in_specs=(P(axis), P(), P()),
-                        out_specs=P(None, axis)))
+                        out_specs=P(None, axis), check_vma=False))
                     searcher._fn_cache[dkey] = dfn
                 from presto_tpu.search.accel import _unpack_scan
                 dense = _unpack_scan(np.asarray(

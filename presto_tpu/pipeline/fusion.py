@@ -502,9 +502,8 @@ def fused_rfft_batch(series_dev, donate: bool = False, obs=None,
         kw = {"donate_argnums": 0} if donate else {}
         if mesh is not None:
             from jax.sharding import PartitionSpec as P
-            from presto_tpu.parallel.sharded import _shard_map
             axis = mesh.axis_names[0]
-            fn = jax.jit(_shard_map(
+            fn = jax.jit(jax.shard_map(
                 jax.vmap(fftpack.realfft_packed_pairs), mesh=mesh,
                 in_specs=P(axis, None),
                 out_specs=P(axis, None, None)), **kw)

@@ -557,10 +557,16 @@ def _seam_fft_search(seam, cfg, passes, manifest=None, obs=None,
     from presto_tpu.ops import fftpack
     from presto_tpu.pipeline import fusion
 
-    try:
-        can_donate = jax.devices()[0].platform != "cpu"
-    except Exception:
-        can_donate = False
+    can_donate = jax.devices()[0].platform != "cpu"
+    # one searcher per (pass, duration, length): the seam chunks of a
+    # pass share its compiled build and scan programs
+    searchers = {}
+
+    def searcher_for(pcfg, T, nbins):
+        key = (pcfg.zmax, pcfg.numharm, pcfg.sigma, pcfg.flo, T, nbins)
+        if key not in searchers:
+            searchers[key] = _searcher_for(pcfg, T, nbins)
+        return searchers[key]
 
     def collect(ent):
         """Search + refine + write one FFT'd chunk (the sync point)."""
@@ -587,7 +593,7 @@ def _seam_fft_search(seam, cfg, passes, manifest=None, obs=None,
             jaxtel.note_put(obs, pairs_host.nbytes)
             _chaos(cfg, "zapbirds-file", obs)
         for pcfg in todo_passes:
-            searcher = _searcher_for(pcfg, T, nbins)
+            searcher = searcher_for(pcfg, T, nbins)
             jaxtel.note_dispatch(obs, "accel_search")
             results = searcher.search_many(search_dev, mesh=mesh,
                                            obs=obs)

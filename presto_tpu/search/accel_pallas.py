@@ -319,7 +319,4 @@ def pick_tile(fracs_zinds, numz: int, slab: int):
 
 def pallas_available() -> bool:
     """True when the default jax backend can run the TPU kernel."""
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"

@@ -354,11 +354,10 @@ class PlanStore:
     Two cooperating layers close the cold-replica problem:
 
       * **JAX's compilation cache** (`enable()`): XLA executables are
-        serialized under `<root>/<fingerprint>/xla/`, so rebuilding a
-        known plan on a fresh replica deserializes instead of
-        recompiling.  Where the backend cannot persist executables
-        the store still works — the sidecar below bounds what must be
-        rebuilt, and `supported` records the degradation.
+        serialized under `<root>/<fingerprint>/xla/` (or the
+        environment's JAX_COMPILATION_CACHE_DIR where it is set), so
+        rebuilding a known plan on a fresh replica deserializes
+        instead of recompiling.
       * **A plan-recipe sidecar** (`plankeys.json`): every plan the
         fleet ever built is recorded with enough to rebuild it
         (`SearcherProvider.prewarm`), merged atomically under a lock
@@ -383,12 +382,14 @@ class PlanStore:
             self.fingerprint.encode()).hexdigest()[:16]
         self.root = os.path.abspath(root)
         self.dir = os.path.join(self.root, fp_id)
-        self.xla_dir = os.path.join(self.dir, "xla")
+        # the environment's cache directory, when it places one, is
+        # the only one (the store then shares it rather than its own)
+        self.xla_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                        or os.path.join(self.dir, "xla"))
         self.sidecar = os.path.join(self.dir, "plankeys.json")
         from presto_tpu.pipeline.leaseledger import _LockDir
         self._lock = _LockDir(self.sidecar + ".lock")
         self.supported: Optional[bool] = None
-        self.enable_error: Optional[str] = None
         reg = obs.metrics
         self._g_warm = reg.gauge(
             "plancache_warm_fraction",
@@ -405,30 +406,19 @@ class PlanStore:
     def enable(self) -> bool:
         """Point JAX's persistent compilation cache at this store's
         fingerprint directory (min-size/min-time thresholds dropped so
-        every bucket executable persists).  Best-effort: a backend or
-        jax version without support degrades to sidecar-only warm-up,
-        recorded in `supported`/`enable_error`."""
+        every bucket executable persists).  Where the environment
+        places the cache (JAX_COMPILATION_CACHE_DIR), that directory
+        stays the only one and this store's xla_dir goes unused."""
+        import jax
         os.makedirs(self.xla_dir, exist_ok=True)
-        try:
-            import jax
-            jax.config.update("jax_compilation_cache_dir",
-                              self.xla_dir)
-            for knob, val in (
-                    ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                    ("jax_persistent_cache_min_entry_size_bytes", -1)):
-                try:
-                    jax.config.update(knob, val)
-                except Exception:
-                    pass                # older jax: keep defaults
-            self.supported = True
-        except Exception as e:
-            self.supported = False
-            self.enable_error = "%s: %s" % (type(e).__name__, e)
-            warnings.warn(
-                "persistent compilation cache unavailable (%s) — "
-                "cold replicas fall back to sidecar prewarm only"
-                % self.enable_error, RuntimeWarning, stacklevel=2)
-        return bool(self.supported)
+        if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+            jax.config.update("jax_compilation_cache_dir", self.xla_dir)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                          -1)
+        self.supported = True
+        return True
 
     def xla_entries(self) -> int:
         """Serialized executables currently on disk (0 when the

@@ -53,6 +53,7 @@ the failure model.
 
 from __future__ import annotations
 
+import glob
 import json
 import os
 import signal
@@ -117,6 +118,25 @@ class SupervisorConfig:
     preempt_interval_s: float = 10.0
     #: the backfill tenant whose lease-holders are preemptable
     preempt_tenant: str = "campaign"
+
+
+def host_chips() -> int:
+    """TPU chips on this host, counted from its device nodes without
+    touching JAX (a supervisor that initialized a backend would hold
+    every chip its replicas need).  A four-chip v5e host shows them
+    as /dev/vfio/0..3 (PR 21); older hosts as /dev/accel*."""
+    return len(glob.glob("/dev/accel[0-9]*")) or len(
+        [p for p in glob.glob("/dev/vfio/[0-9]*")])
+
+
+def chip_env(chip: int) -> Dict[str, str]:
+    """The environment that confines one replica process to one chip
+    of the host.  Established on a four-chip v5e host (PR 21): two
+    processes started at once with chips 0 and 1 each saw one device
+    and computed, with no other setting."""
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
 
 
 def registry_path(fleetdir: str) -> str:
@@ -216,6 +236,9 @@ class FleetSupervisor:
         env["PYTHONPATH"] = (pkg_root + os.pathsep
                              + env.get("PYTHONPATH", "")).rstrip(
                                  os.pathsep)
+        chip = self._reg["replicas"].get(name, {}).get("chip")
+        if chip is not None:
+            env.update(chip_env(chip))
         log = open(os.path.join(logdir, name + ".log"), "ab")
         try:
             proc = subprocess.Popen(
@@ -314,6 +337,14 @@ class FleetSupervisor:
 
     # ---- actuation ---------------------------------------------------
 
+    def _chip_slots(self) -> Optional[int]:  # presto-lint: holds(_lock)
+        """Chips replicas may hold, one each (None: unlimited — the
+        replicas inherit JAX_PLATFORMS=cpu, or the host has no chip)."""
+        plat = os.environ.get("JAX_PLATFORMS", "").split(",")[0]
+        if plat.strip().lower() == "cpu":
+            return None
+        return host_chips() or None       # no chips: CPU replicas
+
     def _spawn_argv(self, name: str) -> List[str]:
         return ([sys.executable, "-m", "presto_tpu.apps.serve",
                  "-fleet", self.cfg.fleetdir,
@@ -331,12 +362,28 @@ class FleetSupervisor:
         disk BEFORE the fork, so a crash in between strands a *named*
         row the next supervisor can match to the process table — never
         an anonymous orphan)."""
+        chip = None
+        slots = self._chip_slots()
+        if slots is not None:
+            used = {r.get("chip") for r in self._reg["replicas"].values()}
+            free = [c for c in range(slots) if c not in used]
+            if not free:
+                # one process per chip: a replica without a chip of its
+                # own would fail or hang at its first device call
+                self.events.emit("supervisor-spawn-failed", replica=None,
+                                 why="no free chip (%d chips, all held)"
+                                 % slots)
+                self.obs.event("supervisor-spawn-failed", replica=None)
+                return None
+            chip = free[0]
         self._reg["seq"] = int(self._reg["seq"]) + 1
         name = "%s-%04d" % (self.cfg.replica_prefix, self._reg["seq"])
         self._reg["replicas"][name] = {
             "state": SPAWNING, "pid": None, "spawned": now,
             "deadline": now + self.cfg.spawn_timeout_s, "why": why,
         }
+        if chip is not None:
+            self._reg["replicas"][name]["chip"] = chip
         self._save_registry()
         with self.obs.span("supervisor:spawn", replica=name) as span:
             try:

@@ -310,8 +310,6 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
-    from presto_tpu.apps.common import ensure_backend
-    ensure_backend()
     from presto_tpu.serve.server import SearchService, start_http
     cfg = StreamConfig(lodm=args.lodm, dmstep=args.dmstep,
                        numdms=args.numdms, nsub=args.nsub,

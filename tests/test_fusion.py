@@ -147,12 +147,12 @@ def test_fused_rfft_matches_staged_fft():
 # StageSeam
 # ----------------------------------------------------------------------
 
-def _mk_block(workdir, ntrials=3, n=512, dt=2e-4):
+def _mk_block(workdir, ntrials=3, n=512, dt=2e-4, prefix="t"):
     import jax.numpy as jnp
     from presto_tpu.io.infodata import InfoData
     rng = np.random.default_rng(11)
     host = rng.normal(size=(ntrials, n)).astype(np.float32)
-    names = [os.path.join(workdir, "t_DM%.2f" % (float(i)))
+    names = [os.path.join(workdir, "%s_DM%.2f" % (prefix, float(i)))
              for i in range(ntrials)]
     infos = [InfoData(name=names[i], N=n, dt=dt, dm=float(i))
              for i in range(ntrials)]
@@ -227,6 +227,30 @@ def test_seam_release_drops_device_reference(tmp_path):
     assert block.series_dev is None
     # host copy still serves spills after release
     assert seam.ensure_dat(block.names[0] + ".dat")
+
+
+def test_seam_fft_search_one_searcher_per_pass(tmp_path, monkeypatch):
+    # seam chunks of one shape share a pass's searcher, and with it
+    # the compiled build and scan programs
+    from presto_tpu.pipeline import survey
+    made = []
+    real = survey._searcher_for
+
+    def counting(cfg, T, nbins):
+        made.append(cfg.zmax)
+        return real(cfg, T, nbins)
+
+    monkeypatch.setattr(survey, "_searcher_for", counting)
+    seam = StageSeam(str(tmp_path), durable=False)
+    for prefix in ("a", "b"):
+        seam.add_block(_mk_block(str(tmp_path), n=4096, prefix=prefix))
+    passes = [(0, 2, 2.0, 1.0), (4, 2, 2.0, 1.0)]
+    survey._seam_fft_search(seam, survey.SurveyConfig(), passes)
+    assert sorted(made) == [0, 4]
+    for prefix in ("a", "b"):
+        for z in (0, 4):
+            assert os.path.exists(str(tmp_path / (
+                "%s_DM1.00_ACCEL_%d" % (prefix, z))))
 
 
 # ----------------------------------------------------------------------

@@ -106,6 +106,46 @@ def test_spawn_waits_for_hysteresis_then_confirms_up(tmp_path):
     assert "supervisor-spawn" in kinds and "supervisor-up" in kinds
 
 
+def test_device_replicas_get_one_chip_each_and_no_more(tmp_path,
+                                                      monkeypatch):
+    """One process per chip: each device-using replica is spawned
+    confined to its own chip, and a spawn past the host's chip count
+    is refused (not started to fail or hang at its first device
+    call); a dead replica's chip goes to its replacement."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.setattr(suplib, "host_chips", lambda: 2)
+    sup = _mksup(tmp_path, cooldown_s=0.0, scale_up_after=1)
+    sup.advice = {"wanted_replicas": 3, "reason": "backlog",
+                  "inputs": {}}
+    for t in range(4):
+        sup.step(now=float(t))
+    rows = sup.replicas()
+    assert sorted(r["chip"] for r in rows.values()) == [0, 1]
+    refused = [e for e in _events(tmp_path)
+               if e["kind"] == "supervisor-spawn-failed"]
+    assert refused and "no free chip" in refused[0]["why"]
+    # the spawn environment confines the process to its chip
+    name = min(rows, key=lambda n: rows[n]["chip"])
+    assert suplib.chip_env(rows[name]["chip"])["TPU_VISIBLE_CHIPS"] \
+        == "0"
+    # replica on chip 0 dies: its replacement takes chip 0 again
+    for n in rows:
+        sup.ledger.heartbeat(n, 0, now=4.5)
+    sup.step(now=5.0)
+    sup.table.pop(name)
+    sup.step(now=6.0)
+    assert sorted(r["chip"] for r in sup.replicas().values()) == [0, 1]
+
+
+def test_cpu_replicas_need_no_chip(tmp_path, monkeypatch):
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    sup = _mksup(tmp_path)
+    assert sup._chip_slots() is None
+    sup.step(now=0.0)
+    sup.step(now=1.0)
+    assert all("chip" not in r for r in sup.replicas().values())
+
+
 def test_cooldown_withholds_and_emits_hold_event(tmp_path):
     sup = _mksup(tmp_path)
     sup.step(now=0.0)

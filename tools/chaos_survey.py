@@ -152,8 +152,6 @@ def main(argv=None) -> int:
     print("chaos_survey: scratch=%s seed=%d trials=%d"
           % (root, args.seed, args.trials))
 
-    from presto_tpu.apps.common import ensure_backend
-    ensure_backend()
     from presto_tpu.pipeline.survey import run_survey
     from presto_tpu.serve.plancache import PlanCache, SearcherProvider
     provider = SearcherProvider(PlanCache(capacity=8))

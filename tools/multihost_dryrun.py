@@ -175,11 +175,12 @@ def per_shard(local, kern, sc):
     return jnp.moveaxis(packed, 1, 0)     # [3, nd_loc, nsl, st, k]
 
 
-from presto_tpu.parallel.sharded import _shard_map
 
-fn = jax.jit(_shard_map(per_shard, mesh=mesh,
+# check_vma off, as parallel/sharded.compact_search_fn: on a TPU the
+# Pallas kernels' outputs carry no mesh-axis variance
+fn = jax.jit(jax.shard_map(per_shard, mesh=mesh,
                         in_specs=(P("dm"), P(), P()),
-                        out_specs=P(None, "dm")))
+                        out_specs=P(None, "dm"), check_vma=False))
 dmsh = NamedSharding(mesh, P("dm"))
 gbatch = jax.make_array_from_callback(
     batch.shape, dmsh, lambda idx: batch[idx])

@@ -1728,8 +1728,6 @@ def main(argv=None) -> int:
 
     if args.campaign:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        from presto_tpu.apps.common import ensure_backend
-        ensure_backend()
         report = run_campaign_loadgen(workdir, timeout=args.timeout)
         text = json.dumps(report, indent=1, sort_keys=True)
         if args.commit:
@@ -1744,8 +1742,6 @@ def main(argv=None) -> int:
 
     if args.supervisor:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        from presto_tpu.apps.common import ensure_backend
-        ensure_backend()
         report = run_supervisor_loadgen(workdir,
                                         timeout=args.timeout)
         text = json.dumps(report, indent=1, sort_keys=True)
@@ -1761,8 +1757,6 @@ def main(argv=None) -> int:
 
     if args.slo:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        from presto_tpu.apps.common import ensure_backend
-        ensure_backend()
         report = run_slo_loadgen(workdir, timeout=args.timeout)
         text = json.dumps(report, indent=1, sort_keys=True)
         if args.commit:
@@ -1777,8 +1771,6 @@ def main(argv=None) -> int:
 
     if args.obs:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        from presto_tpu.apps.common import ensure_backend
-        ensure_backend()
         report = run_obs_loadgen(workdir, timeout=args.timeout)
         text = json.dumps(report, indent=1, sort_keys=True)
         if args.commit:
@@ -1793,8 +1785,6 @@ def main(argv=None) -> int:
 
     if args.dag:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        from presto_tpu.apps.common import ensure_backend
-        ensure_backend()
         Ns = tuple(int(n) for n in args.Ns.split(",") if n.strip())
         report = run_dag_loadgen(workdir, Ns=Ns,
                                  timeout=args.timeout)
@@ -1811,8 +1801,6 @@ def main(argv=None) -> int:
 
     if args.stacked:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        from presto_tpu.apps.common import ensure_backend
-        ensure_backend()
         Ns = tuple(int(n) for n in args.Ns.split(",") if n.strip())
         report = run_stacked_loadgen(workdir, Ns=Ns,
                                      nsamp=args.nsamp
@@ -1834,8 +1822,6 @@ def main(argv=None) -> int:
                        nchan=args.nchan)
 
     if args.replicas:
-        from presto_tpu.apps.common import ensure_backend
-        ensure_backend()
         report = run_fleet_loadgen(workdir, beams,
                                    replicas=args.replicas,
                                    rate=args.rate,
@@ -1848,8 +1834,6 @@ def main(argv=None) -> int:
     service = httpd = None
     url = args.url
     if args.selfhost:
-        from presto_tpu.apps.common import ensure_backend
-        ensure_backend()
         from presto_tpu.serve.server import SearchService, start_http
         service = SearchService(os.path.join(workdir, "serve")).start()
         httpd = start_http(service)
