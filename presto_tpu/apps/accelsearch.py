@@ -18,6 +18,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from presto_tpu.apps.common import load_spectrum, load_timeseries
+from presto_tpu.obs import maybe_span
 from presto_tpu.ops import fftpack
 from presto_tpu.ops.rednoise import (deredden, read_birds_bary, zap_bins,
                                      birds_to_bin_ranges)
@@ -168,12 +169,14 @@ def write_accel_file(path: str, cands, T: float,
 
 def refine_and_write(raw_cands, amps, T, searcher, base, zmax,
                      wmax=0, quiet=False, harmremove=True,
-                     harmpolish=True, lobin=0):
+                     harmpolish=True, lobin=0, obs=None):
     """Candidate post-processing shared by the CLI and the batched
     survey path: harmonic elimination (unless -noharmremove),
     Fourier-domain refinement (+ optional rzw jerk polish), dedup,
     ACCEL/.cand artifacts.  lobin shifts reported frequencies for
-    spectra chopped out of a longer FFT (obs->lobin semantics)."""
+    spectra chopped out of a longer FFT (obs->lobin semantics).
+    ``obs`` (an Observability handle or None) times the artifact
+    writes as an ``accel:write`` span."""
     if harmremove:
         raw_cands = eliminate_harmonics(raw_cands)
     cands = remove_duplicates(raw_cands)
@@ -246,8 +249,9 @@ def refine_and_write(raw_cands, amps, T, searcher, base, zmax,
     accelnm = "%s_ACCEL_%d" % (base, zmax)
     if wmax:
         accelnm += "_JERK_%d" % wmax
-    write_accel_file(accelnm, cands, T, with_w=bool(wmax))
-    write_cand_file(accelnm + ".cand", cands)
+    with maybe_span(obs, "accel:write"):
+        write_accel_file(accelnm, cands, T, with_w=bool(wmax))
+        write_cand_file(accelnm + ".cand", cands)
     if not quiet:
         print("accelsearch: %d raw -> %d final candidates -> %s"
               % (len(raw_cands), len(cands), accelnm))

@@ -16,6 +16,7 @@ import numpy as np
 from presto_tpu.io.infodata import InfoData, read_inf
 from presto_tpu.io.sigproc import FilterbankFile
 from presto_tpu.io import datfft
+from presto_tpu.obs import resolve_obs
 
 
 def add_common_flags(p: argparse.ArgumentParser) -> None:
@@ -129,7 +130,13 @@ class BlockPrep:
         self._clip_state = None
 
     def __call__(self, block, start_spectra):
-        """block: [T, C] float32 (ascending freq); returns same shape."""
+        """block: [T, C] float32 (ascending freq); returns same shape.
+        Timed as an ``ingest:prep`` span of the process default
+        observability handle."""
+        with resolve_obs(None).span("ingest:prep"):
+            return self._prep(block, start_spectra)
+
+    def _prep(self, block, start_spectra):
         if self.invert:
             block = block[:, ::-1]
         if self.have_mask:

@@ -25,6 +25,7 @@ from presto_tpu.io import native
 from presto_tpu.io.errors import PrestoIOError, read_exact
 from presto_tpu.io.quality import (DataQualityReport, record_zero_runs,
                                    scrub_nonfinite)
+from presto_tpu.obs import resolve_obs
 
 _TELESCOPES = {0: "Fake", 1: "Arecibo", 2: "Ooty", 3: "Nancay", 4: "Parkes",
                5: "Jodrell", 6: "GBT", 7: "GMRT", 8: "Effelsberg"}
@@ -237,16 +238,18 @@ def decode_spectra_block(hdr: FilterbankHeader, raw: np.ndarray,
     file reader, the prefetched feeder path, and the live socket /
     file-tail producers (presto_tpu/stream/source.py): native decoder
     when available, numpy unpack + IF-sum + descending-band flip
-    otherwise."""
-    arr = native.decode_spectra(raw, nspec, hdr.nifs, hdr.nchans,
-                                hdr.nbits, hdr.foff < 0)
-    if arr is None:
-        vals = unpack_bits(raw, hdr.nbits)
-        arr = vals.astype(np.float32).reshape(nspec, hdr.nifs,
-                                              hdr.nchans)
-        arr = arr.sum(axis=1) if hdr.nifs > 1 else arr[:, 0, :]
-        if hdr.foff < 0:
-            arr = np.ascontiguousarray(arr[:, ::-1])
+    otherwise.  Timed as an ``ingest:decode`` span of the process
+    default observability handle."""
+    with resolve_obs(None).span("ingest:decode"):
+        arr = native.decode_spectra(raw, nspec, hdr.nifs, hdr.nchans,
+                                    hdr.nbits, hdr.foff < 0)
+        if arr is None:
+            vals = unpack_bits(raw, hdr.nbits)
+            arr = vals.astype(np.float32).reshape(nspec, hdr.nifs,
+                                                  hdr.nchans)
+            arr = arr.sum(axis=1) if hdr.nifs > 1 else arr[:, 0, :]
+            if hdr.foff < 0:
+                arr = np.ascontiguousarray(arr[:, ::-1])
     return arr
 
 

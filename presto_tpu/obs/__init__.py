@@ -37,7 +37,7 @@ from presto_tpu.obs.trace import (NOOP_SPAN, SpanContext, Tracer,
 
 __all__ = [
     "ObsConfig", "Observability", "get_obs", "configure",
-    "resolve_obs", "MetricsRegistry", "Tracer", "SpanContext",
+    "resolve_obs", "maybe_span", "MetricsRegistry", "Tracer", "SpanContext",
     "FlightRecorder", "find_dumps", "chrome_trace",
     "write_chrome_trace", "NOOP_SPAN",
 ]
@@ -174,6 +174,14 @@ def configure(cfg: ObsConfig) -> Observability:
     with _default_lock:
         _default = Observability(cfg)
     return _default
+
+
+def maybe_span(obs: Optional[Observability], name: str, **attrs):
+    """``obs.span(name, **attrs)`` at a call site that may have been
+    handed no handle: the no-op span for ``obs=None``."""
+    if obs is None:
+        return NOOP_SPAN
+    return obs.span(name, **attrs)
 
 
 def resolve_obs(obj) -> Observability:

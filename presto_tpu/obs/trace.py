@@ -12,14 +12,22 @@ captures ``tracer.context()`` (a SpanContext) and passes it as the
 ``parent=`` of spans started on the worker — the same shape OpenTelemetry
 uses for cross-thread propagation.
 
+While a JAX profiler session records (``PRESTO_TPU_PROFILE=<dir>``,
+``jax.profiler.start_trace``), an open span of an enabled tracer is
+also a ``jax.profiler.TraceAnnotation`` named ``presto:<span name>``,
+so the device trace holds the program's spans on the profiler's own
+clock, each on the line of the thread that ran it.  A span finished on
+another thread than the one that opened it stays out of the device
+trace (closing the annotation there would put it on the wrong line).
+
 Finished spans are buffered (bounded), optionally streamed to a JSONL
 file (one span per line, append-only), and exportable as Chrome/
-Perfetto ``trace_event`` JSON (``write_chrome_trace``) so presto_tpu
-traces sit next to the PRESTO_TPU_PROFILE JAX traces in the same
-viewer.
+Perfetto ``trace_event`` JSON (``write_chrome_trace``).  Both exports
+stamp spans with the wall clock (``time.time()``), which lines up the
+spans of several processes; the device trace keeps its own clock.
 
 A disabled tracer costs one branch: ``span()`` returns a shared no-op
-singleton and records nothing.
+singleton, records nothing and imports nothing from JAX.
 """
 
 from __future__ import annotations
@@ -27,6 +35,7 @@ from __future__ import annotations
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -34,6 +43,23 @@ from collections import deque
 from typing import Dict, List, Optional
 
 from presto_tpu.io.atomic import atomic_write_text
+
+
+#: prefix of a span's annotation in the JAX profiler's trace
+ANNOTATION_PREFIX = "presto:"
+
+_TraceAnnotation = None
+
+
+def _annotation_type():
+    """``jax.profiler.TraceAnnotation`` once the process has imported
+    JAX; None before (a process without JAX has no profiler session to
+    join, and a span does not import it)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation
 
 
 def _new_id(nhex: int) -> str:
@@ -88,6 +114,8 @@ class Span:
         self.status = "ok"
         self.thread = threading.current_thread().name
         self._token: Optional[contextvars.Token] = None
+        self._annotation = None          # open profiler annotation
+        self._tid = threading.get_ident()
 
     @property
     def duration(self) -> float:
@@ -162,9 +190,12 @@ class Tracer:
         self.enabled = enabled
         self._cv: contextvars.ContextVar = contextvars.ContextVar(
             "presto_tpu_span", default=None)
-        self._lock = threading.Lock()  # presto-lint: guards(_finished, _open, _jsonl_fh)
+        self._lock = threading.Lock()  # presto-lint: guards(_finished, _open, _jsonl_fh, _parked)
         self._finished: deque = deque(maxlen=keep)
         self._open: Dict[str, Span] = {}
+        # annotations of spans finished on another thread: dropped once
+        # the profiler has stopped, when dropping one records nothing
+        self._parked: list = []
         self._on_finish = on_finish
         self._jsonl_path = jsonl_path
         self._jsonl_fh = None
@@ -189,8 +220,15 @@ class Tracer:
         sp = Span(self, name, trace_id, _new_id(16), parent_id, attrs)
         if current:
             sp._token = self._cv.set(sp)
+        annotation = _annotation_type()
+        recording = annotation is not None and annotation.is_enabled()
+        if recording:
+            sp._annotation = annotation(ANNOTATION_PREFIX + name)
+            sp._annotation.__enter__()
         with self._lock:
             self._open[sp.span_id] = sp
+            if self._parked and not recording:
+                self._parked = []
         return sp
 
     def _finish(self, span: Span, status: str) -> None:
@@ -198,6 +236,13 @@ class Tracer:
             return
         span.end = time.time()
         span.status = status
+        annotation, span._annotation = span._annotation, None
+        if annotation is not None and threading.get_ident() != span._tid:
+            # closing it here would record it on this thread's line
+            with self._lock:
+                self._parked.append(annotation)
+        elif annotation is not None:
+            annotation.__exit__(None, None, None)
         if span._token is not None:
             try:
                 self._cv.reset(span._token)

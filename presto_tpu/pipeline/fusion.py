@@ -73,6 +73,8 @@ from typing import Dict, Iterator, List, Optional
 
 import numpy as np
 
+from presto_tpu.obs import resolve_obs
+
 #: defaults for the fused pipeline's two depth knobs; the
 #: ``pipeline_inflight_depth`` tune family (tune/space.py) overrides
 #: them per device fingerprint.  Depths only change dispatch/ingest
@@ -184,7 +186,11 @@ class DoubleBufferedIngest:
     of the reference's streaming loop lifted to the whole ingest
     stage.  Items are delivered strictly in order; a producer
     exception is re-raised at the consumer's next pull, and close()
-    always joins the thread."""
+    always joins the thread.
+
+    The process default observability handle times the consumer's
+    wait for an item (``ingest:wait``) and the producer's wait for
+    room in a full queue (``ingest:full``)."""
 
     def __init__(self, source: Iterator, depth: int = DEFAULT_INGEST_DEPTH):
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, int(depth)))
@@ -199,12 +205,20 @@ class DoubleBufferedIngest:
     def _run(self, source) -> None:
         try:
             for item in source:
-                while not self._stop.is_set():
-                    try:
-                        self._q.put(item, timeout=0.1)
-                        break
-                    except queue.Full:
-                        continue
+                if self._stop.is_set():
+                    return
+                try:
+                    self._q.put_nowait(item)
+                    continue
+                except queue.Full:
+                    pass
+                with resolve_obs(None).span("ingest:full"):
+                    while not self._stop.is_set():
+                        try:
+                            self._q.put(item, timeout=0.1)
+                            break
+                        except queue.Full:
+                            continue
                 if self._stop.is_set():
                     return
         except BaseException as e:           # relay to the consumer
@@ -221,7 +235,8 @@ class DoubleBufferedIngest:
         return self
 
     def __next__(self):
-        item = self._q.get()
+        with resolve_obs(None).span("ingest:wait"):
+            item = self._q.get()
         if item is self._done:
             if self._exc is not None:
                 exc, self._exc = self._exc, None

@@ -553,7 +553,7 @@ def _seam_fft_search(seam, cfg, passes, manifest=None, obs=None,
     from dataclasses import replace as _replace
     from presto_tpu.apps.accelsearch import refine_and_write
     from presto_tpu.io import datfft
-    from presto_tpu.obs import jaxtel
+    from presto_tpu.obs import jaxtel, maybe_span
     from presto_tpu.ops import fftpack
     from presto_tpu.pipeline import fusion
 
@@ -571,38 +571,49 @@ def _seam_fft_search(seam, cfg, passes, manifest=None, obs=None,
     def collect(ent):
         """Search + refine + write one FFT'd chunk (the sync point)."""
         (block, rows, pairs_dev, todo_passes, n, mesh) = ent
+        with maybe_span(obs, "fused-collect", files=len(rows), nbins=n):
+            _collect(block, rows, pairs_dev, todo_passes, n, mesh)
+
+    def _collect(block, rows, pairs_dev, todo_passes, n, mesh):
         nbins = n // 2
         T = block.numout * fusion.inf_float(block.dt)
-        if mesh is not None:
-            # per-shard D2H (candidate collection + durable spill)
-            pairs_host = fusion.gather_shards(pairs_dev, obs=obs)
-        else:
-            pairs_host = np.array(pairs_dev)      # one download
-            jaxtel.note_get(obs, pairs_host.nbytes)
+        # the download waits for the chunk's rFFT
+        with maybe_span(obs, "seam:download") as sp:
+            if mesh is not None:
+                # per-shard D2H (candidate collection + durable spill)
+                pairs_host = fusion.gather_shards(pairs_dev, obs=obs)
+            else:
+                pairs_host = np.array(pairs_dev)      # one download
+                jaxtel.note_get(obs, pairs_host.nbytes)
+            sp.set_attr("bytes", pairs_host.nbytes)
         search_dev = pairs_dev
         if zap and cfg.zaplist:
             from presto_tpu.apps.zapbirds import zap_pairs_batch
-            pairs_host = zap_pairs_batch(pairs_host, cfg.zaplist, T,
-                                         block.numout)
-            if mesh is not None:      # re-upload zapped, per shard
-                from presto_tpu.parallel.mesh import dm_sharding
-                search_dev = jax.device_put(pairs_host,
-                                            dm_sharding(mesh, 3))
-            else:
-                search_dev = jnp.asarray(pairs_host)
+            with maybe_span(obs, "seam:zap"):
+                pairs_host = zap_pairs_batch(pairs_host, cfg.zaplist, T,
+                                             block.numout)
+            with maybe_span(obs, "seam:upload", bytes=pairs_host.nbytes):
+                if mesh is not None:      # re-upload zapped, per shard
+                    from presto_tpu.parallel.mesh import dm_sharding
+                    search_dev = jax.device_put(pairs_host,
+                                                dm_sharding(mesh, 3))
+                else:
+                    search_dev = jnp.asarray(pairs_host)
             jaxtel.note_put(obs, pairs_host.nbytes)
             _chaos(cfg, "zapbirds-file", obs)
         for pcfg in todo_passes:
             searcher = searcher_for(pcfg, T, nbins)
             jaxtel.note_dispatch(obs, "accel_search")
-            results = searcher.search_many(search_dev, mesh=mesh,
-                                           obs=obs)
+            with maybe_span(obs, "accel:search", zmax=pcfg.zmax):
+                results = searcher.search_many(search_dev, mesh=mesh,
+                                               obs=obs)
             arts = []
             for row, pr, raw in zip(rows, pairs_host, results):
                 name = block.names[row]
-                amps = fftpack.np_pairs_to_complex64(pr)
-                refine_and_write(raw, amps, T, searcher, name,
-                                 pcfg.zmax, quiet=True)
+                with maybe_span(obs, "accel:refine", cands=len(raw)):
+                    amps = fftpack.np_pairs_to_complex64(pr)
+                    refine_and_write(raw, amps, T, searcher, name,
+                                     pcfg.zmax, quiet=True, obs=obs)
                 acc = name + "_ACCEL_%d" % pcfg.zmax
                 arts += [acc, acc + ".cand"]
             _record(manifest, arts, "accel" if zap else "fft+accel")
@@ -688,6 +699,8 @@ def _seam_fft_search(seam, cfg, passes, manifest=None, obs=None,
                     pairs_dev = fusion.fused_rfft_batch(
                         block.series_dev[np.asarray(chunk_rows), :n],
                         obs=obs)
+                if span is not None:      # this chunk's dispatch only
+                    span.finish()
                 pending.append((block, chunk_rows, pairs_dev,
                                 todo_passes, n, chunk_mesh))
                 window = (shard_depth if chunk_mesh is not None
@@ -695,8 +708,6 @@ def _seam_fft_search(seam, cfg, passes, manifest=None, obs=None,
                 while len(pending) >= max(window, 1):
                     collect(pending.pop(0))
                     ndone += 1
-                if span is not None:
-                    span.finish()
     while pending:
         collect(pending.pop(0))
         ndone += 1

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 from functools import lru_cache, partial
 
+from presto_tpu.obs import maybe_span
 from presto_tpu.ops import responses as resp
 from presto_tpu.ops import stats as st
 from presto_tpu.utils.psr import next2_to_n
@@ -1602,10 +1603,12 @@ class AccelSearch:
         are test-pinned equal to this method's); None keeps the
         single-device grouped path.
 
-        ``obs``: an Observability handle — when enabled, the scan
-        program's per-dispatch FLOP/byte unit cost is harvested once
-        per geometry (obs/costmodel.probe, kind "accel_search") so
-        the survey's dispatch accounting carries silicon cost.
+        ``obs``: an Observability handle or None — when enabled, the
+        scan program's per-dispatch FLOP/byte unit cost is harvested
+        once per geometry (obs/costmodel.probe, kind "accel_search") so
+        the survey's dispatch accounting carries silicon cost, and each
+        group's host sync (the wait on its build and scan, then the
+        candidate decode) is an ``accel:collect`` span.
         """
         cfg = self.cfg
         if mesh is not None and len(list(mesh.devices.flat)) > 1:
@@ -1652,10 +1655,12 @@ class AccelSearch:
         if mkey not in self._fn_cache:
             if "plb" in key:
                 # pallas_call + vmap is unsupported; sequential map is
-                # fine (each build saturates the chip on its own)
-                self._fn_cache[mkey] = jax.jit(
-                    lambda batch, kd: jax.lax.map(
-                        lambda b: build_one(b, kd), batch))
+                # fine (each build saturates the chip on its own).  The
+                # named function names the program in device traces.
+                def build_planes(batch, kd):
+                    return jax.lax.map(lambda b: build_one(b, kd), batch)
+
+                self._fn_cache[mkey] = jax.jit(build_planes)
             else:
                 self._fn_cache[mkey] = jax.jit(
                     jax.vmap(build_one, in_axes=(0, None)))
@@ -1699,22 +1704,23 @@ class AccelSearch:
             """The host sync for one dispatched group."""
             nonlocal done
             g0, planes, comp_dev = ent
-            comp = np.asarray(comp_dev)
-            dense = None
-            for d in range(comp.shape[0]):
-                if g0 + d < done:
-                    continue               # overlap: already collected
-                try:
-                    cands = self.collect_compacted(
-                        comp[d], start_cols, requested_m=compact_m)
-                except ValueError:
-                    if dense is None:
-                        dense = _unpack_scan(
-                            scanner.many(planes, scols))
-                    vals, cidx, zrow = dense
-                    cands = collect_dm(vals[d], cidx[d], zrow[d])
-                out.append(cands)
-                done = g0 + d + 1
+            with maybe_span(obs, "accel:collect"):
+                comp = np.asarray(comp_dev)
+                dense = None
+                for d in range(comp.shape[0]):
+                    if g0 + d < done:
+                        continue           # overlap: already collected
+                    try:
+                        cands = self.collect_compacted(
+                            comp[d], start_cols, requested_m=compact_m)
+                    except ValueError:
+                        if dense is None:
+                            dense = _unpack_scan(
+                                scanner.many(planes, scols))
+                        vals, cidx, zrow = dense
+                        cands = collect_dm(vals[d], cidx[d], zrow[d])
+                    out.append(cands)
+                    done = g0 + d + 1
 
         # 2-deep in-flight window (the jerk ladder's pattern, see
         # pipeline/fusion.InflightWindow): group i+1's build+scan is
