@@ -131,12 +131,16 @@ class BlockPrep:
 
     def __call__(self, block, start_spectra):
         """block: [T, C] float32 (ascending freq); returns same shape.
+        A block that owns its writable data (a decoder's fresh output)
+        is clipped in place; a view or read-only data is copied first.
         Timed as an ``ingest:prep`` span of the process default
-        observability handle."""
-        with resolve_obs(None).span("ingest:prep"):
-            return self._prep(block, start_spectra)
+        observability handle, which also counts the clipped blocks by
+        path and the rows replaced."""
+        obs = resolve_obs(None)
+        with obs.span("ingest:prep"):
+            return self._prep(block, start_spectra, obs)
 
-    def _prep(self, block, start_spectra):
+    def _prep(self, block, start_spectra, obs):
         if self.invert:
             block = block[:, ::-1]
         if self.have_mask:
@@ -147,8 +151,12 @@ class BlockPrep:
             elif n > 0:
                 block = self._mask_block(block, chans, self.padvals)
         if self.clip > 0:
-            block, _, self._clip_state = self._clip_times(
-                block, self.clip, self._clip_state)
+            inplace = block.flags.writeable and block.flags.owndata
+            if not inplace:
+                block = block.copy()
+            block, nclip, self._clip_state = self._clip_times(
+                block, self.clip, self._clip_state, out=block)
+            _note_clip(obs, "inplace" if inplace else "copy", nclip)
         if self.zerodm:
             block = self._remove_zerodm(
                 block, self.padvals if self.have_mask else None)
@@ -159,6 +167,20 @@ class BlockPrep:
         if self.ignore is not None:
             block[:, self.ignore] = 0.0
         return block
+
+
+def _note_clip(obs, path: str, nrows: int) -> None:
+    """One clipped block on ``path`` (inplace | copy) and the rows the
+    clipper replaced in it."""
+    if not obs.enabled:
+        return
+    obs.metrics.counter(
+        "ingest_clip_blocks_total",
+        "Blocks BlockPrep clipped, in place or on a copy",
+        ("path",)).labels(path=path).inc()
+    obs.metrics.counter(
+        "ingest_clipped_rows_total",
+        "Time samples the clipper replaced").inc(nrows)
 
 
 class CLIResume:

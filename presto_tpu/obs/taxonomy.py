@@ -632,6 +632,9 @@ METRICS = frozenset({
     "ingest_scrubbed_samples_total",
     "ingest_quarantined_spectra_total",
     "ingest_reports_total",
+    # clipping in apps/common.BlockPrep
+    "ingest_clip_blocks_total",
+    "ingest_clipped_rows_total",
     # jax compile/device telemetry
     "jax_compiles_total",
     "jax_compile_seconds",

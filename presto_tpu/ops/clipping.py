@@ -8,7 +8,9 @@ The reference keeps the clipper's running state in function statics
 (clipping.c:56-61) — single-stream only.  Here the state is an explicit
 dataclass threaded by the caller (pure-function policy, SURVEY.md §5.2).
 Runs in numpy: it sits in the host read path before data reach the
-device, on small per-block arrays.
+device, once per raw block.  clip_times reads the block twice (row
+sums, then the good rows' channel means as a matvec) and, given
+``out=block``, writes only the clipped rows.
 """
 
 from __future__ import annotations
@@ -29,7 +31,8 @@ class ClipState:
 
 
 def clip_times(block: np.ndarray, clip_sigma: float,
-               state: Optional[ClipState] = None
+               state: Optional[ClipState] = None,
+               out: Optional[np.ndarray] = None
                ) -> Tuple[np.ndarray, int, ClipState]:
     """Clip RFI-contaminated time samples in one raw block.
 
@@ -37,6 +40,13 @@ def clip_times(block: np.ndarray, clip_sigma: float,
     Samples whose zero-DM (band-summed) value deviates more than
     clip_sigma from the running mean are replaced by the per-channel
     running averages.  Returns (clipped_block, nclipped, new_state).
+
+    out: where the clipped block goes, as in numpy's ufuncs.  None (the
+    default) returns a new array and leaves ``block`` untouched;
+    ``out=block`` clips in place, writing only the clipped rows; any
+    other array of the block's shape receives a copy of the block with
+    the clipped rows replaced.  The statistics read ``block`` before
+    anything is written.
 
     Algorithm parity with clipping.c:48-:
       1. zero-DM series; median + std
@@ -64,7 +74,11 @@ def clip_times(block: np.ndarray, clip_sigma: float,
     else:
         current_avg = float(zero_dm[good].mean())
         current_std = float(zero_dm[good].std())
-        chan_avg = block[good].mean(axis=0)
+        # the good rows' channel means as one masked matvec (no gather
+        # of the good rows); any non-finite row sum makes the std NaN
+        # and ``good`` empty, so every weighted row here is finite
+        weights = good.astype(np.result_type(block.dtype, np.float32))
+        chan_avg = (weights @ block) / ngood
 
     if state.blocksread:
         running_avg = 0.9 * state.running_avg + 0.1 * current_avg
@@ -77,14 +91,18 @@ def clip_times(block: np.ndarray, clip_sigma: float,
 
     trigger = clip_sigma * running_std
     bad = np.abs(zero_dm - running_avg) > trigger
-    out = block.copy()
-    if bad.any():
+    if out is None:
+        out = block.copy()
+    elif out is not block:
+        np.copyto(out, block)
+    nbad = int(bad.sum())
+    if nbad:
         out[bad] = chan_running.astype(np.float32)
     new_state = ClipState(chan_running_avg=chan_running,
                           running_avg=running_avg,
                           running_std=running_std,
                           blocksread=state.blocksread + 1)
-    return out, int(bad.sum()), new_state
+    return out, nbad, new_state
 
 
 def remove_zerodm(block: np.ndarray,

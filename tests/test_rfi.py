@@ -157,3 +157,149 @@ def test_calc_avgmedstd_matches_definition():
     assert np.isclose(avg, mid.mean())
     assert np.isclose(med, s[50])
     assert np.isclose(std, mid.std())
+
+
+# ----------------------------------------------------------------------
+# the one-pass clip: in place, on a copy, against the gather algorithm
+# ----------------------------------------------------------------------
+
+def _clip_times_gather(block, clip_sigma, state=None):
+    """The gather form of the clipper, the reference for the one-pass
+    form: a copy of the block, the good rows gathered for their channel
+    means."""
+    if state is None:
+        state = ClipState()
+    zero_dm = block.sum(axis=1).astype(np.float64)
+    med, std = float(np.median(zero_dm)), float(zero_dm.std())
+    good = (zero_dm > med - 3.0 * std) & (zero_dm < med + 3.0 * std)
+    if good.sum() < 1:
+        avg, std = state.running_avg, state.running_std
+        chan_avg = (state.chan_running_avg if state.chan_running_avg
+                    is not None else block.mean(axis=0))
+    else:
+        avg, std = float(zero_dm[good].mean()), float(zero_dm[good].std())
+        chan_avg = block[good].mean(axis=0)
+    if state.blocksread:
+        running_avg = 0.9 * state.running_avg + 0.1 * avg
+        running_std = 0.9 * state.running_std + 0.1 * std
+        chan_running = 0.9 * state.chan_running_avg + 0.1 * chan_avg
+    else:
+        running_avg, running_std = avg, std
+        chan_running = chan_avg.astype(np.float64)
+    bad = np.abs(zero_dm - running_avg) > clip_sigma * running_std
+    out = block.copy()
+    out[bad] = chan_running.astype(np.float32)
+    return out, bad, ClipState(chan_running, running_avg, running_std,
+                               state.blocksread + 1)
+
+
+def _bursty_blocks(seed, nblocks, ptsperblk, numchan):
+    """Float blocks over a sloped bandpass with 1-3 broadband bursts of
+    1-24 samples each."""
+    rng = np.random.default_rng(seed)
+    bandpass = np.linspace(80.0, 110.0, numchan)
+    for _ in range(nblocks):
+        block = (bandpass + rng.normal(0, 12.0, (ptsperblk, numchan))
+                 ).astype(np.float32)
+        for _ in range(rng.integers(1, 4)):
+            n = int(rng.integers(1, 25))
+            t0 = int(rng.integers(0, ptsperblk - n))
+            block[t0:t0 + n] += rng.uniform(20.0, 100.0)
+        yield block
+
+
+@pytest.mark.parametrize("seed,ptsperblk,numchan", [
+    (0, 512, 16), (1, 512, 16), (2, 1024, 64), (3, 1024, 64),
+    (4, 2048, 96), (5, 4096, 32)])
+@pytest.mark.parametrize("path", ["inplace", "copy", "out"])
+def test_one_pass_clip_matches_gather(seed, ptsperblk, numchan, path):
+    ref_state = state = None
+    nclipped = 0
+    for block in _bursty_blocks(seed, 4, ptsperblk, numchan):
+        want, bad, ref_state = _clip_times_gather(block, 6.0, ref_state)
+        if path == "inplace":
+            got, nclip, state = clip_times(block, 6.0, state, out=block)
+            assert got is block
+        elif path == "copy":
+            got, nclip, state = clip_times(block, 6.0, state)
+        else:
+            dest = np.full_like(block, np.nan)
+            got, nclip, state = clip_times(block, 6.0, state, out=dest)
+            assert got is dest
+        assert nclip == int(bad.sum())
+        nclipped += nclip
+        assert state.blocksread == ref_state.blocksread
+        assert state.running_avg == ref_state.running_avg
+        assert state.running_std == ref_state.running_std
+        np.testing.assert_allclose(state.chan_running_avg,
+                                   ref_state.chan_running_avg, rtol=1e-5)
+        np.testing.assert_array_equal(got[~bad], want[~bad])
+        np.testing.assert_allclose(got[bad], want[bad], rtol=1e-5)
+    assert nclipped > 0
+
+
+def test_clip_without_out_leaves_block_unchanged():
+    block = next(_bursty_blocks(7, 1, 512, 16))
+    before = block.copy()
+    out, nclip, _ = clip_times(block, 6.0)
+    assert nclip > 0 and out is not block
+    np.testing.assert_array_equal(block, before)
+
+
+@pytest.fixture
+def default_obs():
+    """An enabled process-default handle, restored afterwards."""
+    from presto_tpu import obs as obsmod
+    saved = obsmod._default
+    obs = obsmod.configure(obsmod.ObsConfig(enabled=True))
+    yield obs
+    obsmod._default = saved
+
+
+def _clip_counts(obs):
+    blocks = obs.metrics.counter("ingest_clip_blocks_total",
+                                 labelnames=("path",))
+    return ({p: blocks.labels(path=p).value for p in ("inplace", "copy")},
+            obs.metrics.counter("ingest_clipped_rows_total").value)
+
+
+def _prep(invert=False):
+    from types import SimpleNamespace
+    from presto_tpu.apps.common import BlockPrep
+    args = SimpleNamespace(clip=6.0, noclip=False, invert=invert,
+                           zerodm=False, runavg=False)
+    return BlockPrep(16, 1e-3, args)
+
+
+def test_blockprep_clips_a_decoded_block_in_place(default_obs):
+    from presto_tpu.io.sigproc import FilterbankHeader, decode_spectra_block
+    hdr = FilterbankHeader(fch1=1500.0, foff=-1.0, nchans=16, nbits=8,
+                           tsamp=1e-3, nifs=1, N=512)
+    raw = np.random.default_rng(8).integers(90, 110, 512 * 16,
+                                            dtype=np.uint8)
+    raw.reshape(512, 16)[200:203] = 250           # a broadband burst
+    block = decode_spectra_block(hdr, raw, 512)
+    assert block.flags.owndata and block.flags.writeable
+    want, nclip, _ = clip_times(block, 6.0)
+    got = _prep()(block, 0)
+    assert got is block and nclip > 0
+    np.testing.assert_array_equal(got, want)
+    assert _clip_counts(default_obs) == ({"inplace": 1, "copy": 0}, nclip)
+
+
+@pytest.mark.parametrize("kind", ["readonly", "view"])
+def test_blockprep_copies_what_it_does_not_own(default_obs, kind):
+    block = next(_bursty_blocks(9, 1, 512, 16))
+    if kind == "readonly":
+        data = np.frombuffer(block.tobytes(), np.float32).reshape(512, 16)
+        assert not data.flags.writeable
+        want, nclip, _ = clip_times(block, 6.0)
+    else:
+        data = block
+        want, nclip, _ = clip_times(block[:, ::-1].copy(), 6.0)
+    before = data.copy()
+    got = _prep(invert=(kind == "view"))(data, 0)
+    assert nclip > 0
+    np.testing.assert_array_equal(data, before)
+    np.testing.assert_array_equal(got, want)
+    assert _clip_counts(default_obs) == ({"inplace": 0, "copy": 1}, nclip)
