@@ -202,8 +202,9 @@ def refine_and_write(raw_cands, amps, T, searcher, base, zmax,
         seeds = [AccelCand(power=o.power, sigma=o.sigma,
                            numharm=o.numharm, r=o.r, z=o.z, w=c.w)
                  for c, o in zip(cands, ocs)]
-        jocs = optimize_jerk_cands(amps, seeds, T, searcher.numindep,
-                                   harmpolish=harmpolish)
+        with maybe_span(obs, "accel:jerk-polish", cands=len(seeds)):
+            jocs = optimize_jerk_cands(amps, seeds, T, searcher.numindep,
+                                       harmpolish=harmpolish)
     refined = []
     for c, oc, joc in zip(cands, ocs, jocs):
         try:

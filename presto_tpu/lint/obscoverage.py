@@ -143,6 +143,8 @@ EVENT_ATTR_RE = re.compile(r'^\s*EV_[A-Z_]+\s*=\s*"([^"]+)"',
 METRIC_RE = re.compile(
     r'\.(?:counter|gauge|histogram)\(\s*\n?\s*"([a-z0-9_]+)"')
 SPAN_RE = re.compile(r'\.span\(\s*\n?\s*"([^"]+)"')
+#: spans opened through obs.maybe_span(handle, "name", ...)
+MAYBE_SPAN_RE = re.compile(r'maybe_span\(\s*\w+,\s*\n?\s*"([^"]+)"')
 
 
 def _read(relpath: str, root: str) -> str:
@@ -1055,6 +1057,33 @@ def lint(root: Optional[str] = None) -> List[str]:
         problems.append(
             "triage layer: metric %r is not registered in "
             "obs/taxonomy.TRIAGE_METRICS" % name)
+
+    # 21. the jerk volume (search/jerk.py + apps/accelsearch.py):
+    # JERK_SPANS / JERK_METRICS pinned BOTH directions (the per-layer
+    # readers of the jerk cell read these names)
+    jk_spans, jk_metrics = set(), set()
+    for rel, src in _tree_sources(root, "presto_tpu/search",
+                                  "presto_tpu/apps").items():
+        jk_spans |= {s for s in MAYBE_SPAN_RE.findall(src)
+                     if s == "accel:wbank" or s.startswith("accel:jerk")}
+        jk_metrics |= {m for m in METRIC_RE.findall(src)
+                       if m.startswith(("accel_jerk_", "accel_wbank_"))}
+    for s in sorted(taxonomy.JERK_SPANS ^ jk_spans):
+        problems.append(
+            "jerk volume: span %r is %s" % (
+                s, "listed in obs/taxonomy.JERK_SPANS but never opened"
+                if s in taxonomy.JERK_SPANS
+                else "not registered in obs/taxonomy.JERK_SPANS"))
+    for name in sorted(taxonomy.JERK_METRICS - taxonomy.METRICS):
+        problems.append(
+            "obs/taxonomy.py: JERK_METRICS lists %r which is not in "
+            "METRICS" % name)
+    for name in sorted(taxonomy.JERK_METRICS ^ jk_metrics):
+        problems.append(
+            "jerk volume: metric %r is %s" % (
+                name, "listed in obs/taxonomy.JERK_METRICS but never "
+                "registered" if name in taxonomy.JERK_METRICS
+                else "not registered in obs/taxonomy.JERK_METRICS"))
     return problems
 
 

@@ -595,6 +595,21 @@ FLEET_METRICS = frozenset({
     "fleet_obs_aggregations_total",
 })
 
+#: jerk-volume span names: the w kernel banks' quadrature and upload,
+#: each trial's dispatch group of the (r, z, w) volume
+#: (search/jerk.py) and the (r, z, w) polish (apps/accelsearch.py);
+#: obs-coverage check 21 pins them and JERK_METRICS both directions
+JERK_SPANS = frozenset({
+    "accel:wbank",
+    "accel:jerk",
+    "accel:jerk-polish",
+})
+
+JERK_METRICS = frozenset({
+    "accel_jerk_cells_built_total",
+    "accel_wbank_builds_total",
+})
+
 #: registered metric names (Prometheus side of the contract); the
 #: linter checks every registry.counter/gauge/histogram call in the
 #: tree registers a name listed here.
@@ -635,6 +650,10 @@ METRICS = frozenset({
     # clipping in apps/common.BlockPrep
     "ingest_clip_blocks_total",
     "ingest_clipped_rows_total",
+    # the banded jerk volume (search/jerk.py); pinned both directions
+    # by obs-coverage check 21 via JERK_METRICS
+    "accel_jerk_cells_built_total",
+    "accel_wbank_builds_total",
     # jax compile/device telemetry
     "jax_compiles_total",
     "jax_compile_seconds",

@@ -32,8 +32,10 @@ class SurveyRecipe:
     rfi_time: float                       # rfifind interval (s)
     # ((zmax, numharm, sigma, flo), ...): first is the primary pass;
     # flo is the per-pass low-frequency search limit in Hz
-    # (lo_accel_flo=2.0 / hi_accel_flo=1.0, PALFA_presto_search.py:39-43)
-    accel_passes: Tuple[Tuple[int, int, float, float], ...]
+    # (lo_accel_flo=2.0 / hi_accel_flo=1.0, PALFA_presto_search.py:39-43).
+    # A jerk pass appends accelsearch's -wmax (and optionally -fhi):
+    # (zmax, numharm, sigma, flo, wmax[, fhi])
+    accel_passes: Tuple[tuple, ...]
     sift: SiftPolicy
     fold_sigma: float                     # to_prepfold_sigma
     max_folds: int                        # max_cands_to_fold (combined)
@@ -107,7 +109,23 @@ GBNCC = SurveyRecipe(
 # analog) or pass --driftprep to the pipeline app.
 GBT350_DRIFT = replace(GBNCC, name="gbt350drift")
 
-RECIPES = {r.name: r for r in (PALFA, GBNCC, GBT350_DRIFT)}
+# Terzan 5 binary-pulsar search (Andersen & Ransom 2018, ApJL 863, L13:
+# PRESTO's Fourier-domain jerk search on GBT S-band observations of the
+# cluster): GBNCC's lo pass and sifting, then one jerk pass at zmax 200
+# / wmax 300 / 8 harmonics.  For a 30 m/s^2 orbit of Pb = 2 h the 8th
+# harmonic of a 300 Hz MSP reaches z ~ 113 and w ~ 68 over a 687 s
+# segment (5-15% of the orbit, where the paper finds the jerk search
+# pays), so the pass covers the cluster's spider binaries with margin.
+TER5 = SurveyRecipe(
+    name="ter5",
+    rfi_time=2.0,
+    accel_passes=((0, 16, 2.0, 2.0), (200, 8, 3.0, 1.0, 300)),
+    sift=GBNCC.sift,
+    fold_sigma=6.0, max_folds=40,
+    sp_threshold=5.0, sp_maxwidth=0.1,
+    nsub=32)
+
+RECIPES = {r.name: r for r in (PALFA, GBNCC, GBT350_DRIFT, TER5)}
 
 
 def get_recipe(name: str) -> SurveyRecipe:

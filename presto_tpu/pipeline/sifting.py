@@ -447,7 +447,10 @@ def select_fold_candidates(cl: Candlist, fold_top: int = 3,
                     "max_folds_per_pass has %d caps for %d accel "
                     "passes" % (len(max_folds_per_pass),
                                 len(pass_zmaxes)))
-            tags = tuple("_ACCEL_%d" % z for z in pass_zmaxes)
+            # an int is a pass's zmax; a str is its whole ACCEL tag
+            # (pipeline/survey.pass_tag: ``_ACCEL_<z>_JERK_<w>``)
+            tags = tuple(z if isinstance(z, str) else "_ACCEL_%d" % z
+                         for z in pass_zmaxes)
             untagged = [c for c in above
                         if not any(c.filename.endswith(t)
                                    for t in tags)]

@@ -50,7 +50,9 @@ class SurveyConfig:
     zaplist: Optional[str] = None
     # extra accelsearch passes beyond (zmax, numharm, sigma[, flo]),
     # e.g. the PALFA lo/hi pair — each entry is (zmax, numharm,
-    # sigma) or (zmax, numharm, sigma, flo); a 3-tuple inherits flo
+    # sigma) or (zmax, numharm, sigma, flo); a 3-tuple inherits flo.
+    # A jerk pass adds accelsearch's -wmax and -fhi: (zmax, numharm,
+    # sigma, flo, wmax[, fhi]); fhi 0 searches up to Nyquist
     accel_passes: Optional[tuple] = None
     # sifting / folding
     min_dm_hits: int = 2
@@ -131,11 +133,28 @@ class SurveyConfig:
 
     @property
     def all_passes(self):
-        """Normalized 4-tuples (zmax, numharm, sigma, flo)."""
+        """The passes as tuples: (zmax, numharm, sigma, flo), or with
+        a jerk pass's (..., wmax[, fhi]) kept."""
         raw = ((self.zmax, self.numharm, self.sigma, self.flo),) + \
             tuple(self.accel_passes or ())
-        return tuple(p if len(p) == 4 else tuple(p) + (self.flo,)
+        return tuple(tuple(p) if len(p) >= 4 else tuple(p) + (self.flo,)
                      for p in raw)
+
+
+def pass_fields(p) -> tuple:
+    """(zmax, numharm, sigma, flo, wmax, fhi) of one normalized pass;
+    a 4-tuple is wmax 0 and fhi 0 (up to Nyquist)."""
+    zmax, nh, sg, flo = p[:4]
+    wmax = int(p[4]) if len(p) > 4 else 0
+    fhi = float(p[5]) if len(p) > 5 else 0.0
+    return int(zmax), int(nh), float(sg), float(flo), wmax, fhi
+
+
+def pass_tag(p) -> str:
+    """The ACCEL artifact suffix of one pass: ``_ACCEL_<zmax>``, and
+    ``_JERK_<wmax>`` after it for a jerk pass (apps/accelsearch)."""
+    zmax, _nh, _sg, _flo, wmax, _fhi = pass_fields(p)
+    return "_ACCEL_%d" % zmax + ("_JERK_%d" % wmax if wmax else "")
 
 
 @dataclass
@@ -458,10 +477,12 @@ def _device_search_stages(seam, disk_only, datfiles, cfg, passes,
         timer.mark("accelsearch")
         # ---- 6. accelsearch: BATCHED over the DM fan-out, once per
         # recipe pass (e.g. PALFA's zmax=0/nh=16 + zmax=50/nh=8) -----
-        for (zmax, nh, sg, flo) in passes:
+        for p in passes:
+            zmax, nh, sg, flo, wmax, fhi = pass_fields(p)
             _batched_accelsearch(
                 fftfiles, _replace(cfg, zmax=zmax, numharm=nh,
-                                   sigma=sg, flo=flo), manifest, obs)
+                                   sigma=sg, flo=flo), manifest, obs,
+                wmax=wmax, fhi=fhi)
     else:
         # ---- 4+6 fused fast path: realfft -> accelsearch with the
         # spectra RESIDENT on device (no zapbirds in between).  Seam
@@ -473,13 +494,14 @@ def _device_search_stages(seam, disk_only, datfiles, cfg, passes,
         if len(seam):
             _seam_fft_search(seam, cfg, passes, manifest, obs)
         _fused_fft_search(disk_only, cfg, manifest, obs)
-        for (zmax, nh, sg, flo) in passes:
+        for p in passes:
             # resume case for the first pass; full searches for the
             # recipe's additional passes
+            zmax, nh, sg, flo, wmax, fhi = pass_fields(p)
             _batched_accelsearch(
                 [f[:-4] + ".fft" for f in disk_only],
                 _replace(cfg, zmax=zmax, numharm=nh, sigma=sg,
-                         flo=flo), manifest, obs)
+                         flo=flo), manifest, obs, wmax=wmax, fhi=fhi)
 
 
 def _length_groups(files, item_bytes):
@@ -501,25 +523,27 @@ def _durable(cfg) -> bool:
     return os.environ.get("PRESTO_TPU_DURABLE", "1") != "0"
 
 
-def _searcher_for(cfg, T, nbins):
+def _searcher_for(cfg, T, nbins, wmax=0, fhi=0.0):
     """One accel searcher for a (pass config, duration, length) —
     through the plan provider when a resident service shares one
     (serve/plancache), so same-shaped trial groups reuse compiled
-    plans across the staged AND seam paths."""
+    plans across the staged AND seam paths.  ``wmax`` and ``fhi`` are
+    a jerk pass's -wmax and -fhi (its band's top, Hz)."""
     from presto_tpu.search.accel import AccelConfig, AccelSearch
     acfg = AccelConfig(zmax=cfg.zmax, numharm=cfg.numharm,
-                       sigma=cfg.sigma, flo=cfg.flo)
+                       sigma=cfg.sigma, flo=cfg.flo, wmax=int(wmax),
+                       rhi=float(fhi) * T if fhi else 0.0)
     if cfg.plan_provider is not None:
         return cfg.plan_provider.searcher(acfg, T, nbins)
     return AccelSearch(acfg, T=T, numbins=nbins)
 
 
-def _survey_searcher(first_file, nbins, cfg):
+def _survey_searcher(first_file, nbins, cfg, wmax=0, fhi=0.0):
     """(searcher, T) for one same-length trial group."""
     from presto_tpu.io.infodata import read_inf
     info = read_inf(first_file[:-4] + ".inf")
     T = info.N * info.dt
-    return _searcher_for(cfg, T, nbins), T
+    return _searcher_for(cfg, T, nbins, wmax, fhi), T
 
 
 def _seam_fft_search(seam, cfg, passes, manifest=None, obs=None,
@@ -562,10 +586,11 @@ def _seam_fft_search(seam, cfg, passes, manifest=None, obs=None,
     # pass share its compiled build and scan programs
     searchers = {}
 
-    def searcher_for(pcfg, T, nbins):
-        key = (pcfg.zmax, pcfg.numharm, pcfg.sigma, pcfg.flo, T, nbins)
+    def searcher_for(pcfg, wmax, fhi, T, nbins):
+        key = (pcfg.zmax, pcfg.numharm, pcfg.sigma, pcfg.flo, wmax, fhi,
+               T, nbins)
         if key not in searchers:
-            searchers[key] = _searcher_for(pcfg, T, nbins)
+            searchers[key] = _searcher_for(pcfg, T, nbins, wmax, fhi)
         return searchers[key]
 
     def collect(ent):
@@ -601,10 +626,11 @@ def _seam_fft_search(seam, cfg, passes, manifest=None, obs=None,
                     search_dev = jnp.asarray(pairs_host)
             jaxtel.note_put(obs, pairs_host.nbytes)
             _chaos(cfg, "zapbirds-file", obs)
-        for pcfg in todo_passes:
-            searcher = searcher_for(pcfg, T, nbins)
+        for pcfg, wmax, fhi, tag in todo_passes:
+            searcher = searcher_for(pcfg, wmax, fhi, T, nbins)
             jaxtel.note_dispatch(obs, "accel_search")
-            with maybe_span(obs, "accel:search", zmax=pcfg.zmax):
+            with maybe_span(obs, "accel:search", zmax=pcfg.zmax,
+                            **({"wmax": wmax} if wmax else {})):
                 results = searcher.search_many(search_dev, mesh=mesh,
                                                obs=obs)
             arts = []
@@ -613,8 +639,8 @@ def _seam_fft_search(seam, cfg, passes, manifest=None, obs=None,
                 with maybe_span(obs, "accel:refine", cands=len(raw)):
                     amps = fftpack.np_pairs_to_complex64(pr)
                     refine_and_write(raw, amps, T, searcher, name,
-                                     pcfg.zmax, quiet=True, obs=obs)
-                acc = name + "_ACCEL_%d" % pcfg.zmax
+                                     pcfg.zmax, wmax, quiet=True, obs=obs)
+                acc = name + tag
                 arts += [acc, acc + ".cand"]
             _record(manifest, arts, "accel" if zap else "fft+accel")
         if seam.durable:
@@ -640,10 +666,11 @@ def _seam_fft_search(seam, cfg, passes, manifest=None, obs=None,
             mesh = block.mesh if sharded else None
             ndev = (len(list(mesh.devices.flat)) if sharded else 1)
             # the staged consumers' verify-or-redo contract, per trial
+            tags = [pass_tag(p) for p in passes]
             arts = []
             for name in block.names:
-                for (zmax, _nh, _sg, _flo) in passes:
-                    acc = name + "_ACCEL_%d" % zmax
+                for tag in tags:
+                    acc = name + tag
                     arts += [acc, acc + ".cand"]
             _drop_stale(manifest, arts)
             rows = []
@@ -653,18 +680,20 @@ def _seam_fft_search(seam, cfg, passes, manifest=None, obs=None,
                         manifest.stage_of(name + ".fft") == "zapbirds":
                     continue     # journaled zapped spectrum: disk path
                 need = any(
-                    not (_valid(manifest, name + "_ACCEL_%d" % zmax)
-                         and _valid(manifest,
-                                    name + "_ACCEL_%d.cand" % zmax))
-                    for (zmax, _nh, _sg, _flo) in passes)
+                    not (_valid(manifest, name + tag)
+                         and _valid(manifest, name + tag + ".cand"))
+                    for tag in tags)
                 if need or (seam.durable
                             and not _valid(manifest, name + ".fft")):
                     rows.append(row)
             if not rows:
                 continue
-            todo_passes = [_replace(cfg, zmax=z, numharm=nh, sigma=sg,
-                                    flo=flo)
-                           for (z, nh, sg, flo) in passes]
+            todo_passes = []
+            for p in passes:
+                z, nh, sg, flo, wmax, fhi = pass_fields(p)
+                todo_passes.append((_replace(cfg, zmax=z, numharm=nh,
+                                             sigma=sg, flo=flo),
+                                    wmax, fhi, pass_tag(p)))
             # memory budget is per DEVICE: a sharded whole-block holds
             # numdms/ndev rows on each chip
             per = max(1, int(2 ** 30 // max(n * 4, 1))) * ndev
@@ -929,10 +958,12 @@ def _staged_fft_search_head(datfiles, cfg, manifest=None, obs=None):
         print("survey: realfft over %d series (batched)" % len(todo))
 
 
-def _batched_accelsearch(fftfiles, cfg, manifest=None, obs=None):
+def _batched_accelsearch(fftfiles, cfg, manifest=None, obs=None,
+                         wmax=0, fhi=0.0):
     """Stage 6 alone (staged path): grouped search_many over .fft
-    files already on disk."""
-    accs = [f[:-4] + "_ACCEL_%d" % cfg.zmax for f in fftfiles]
+    files already on disk (``wmax``/``fhi``: a jerk pass's)."""
+    tag = pass_tag((cfg.zmax, cfg.numharm, cfg.sigma, cfg.flo, wmax))
+    accs = [f[:-4] + tag for f in fftfiles]
     # the ACCEL table and its binary .cand companion are one logical
     # artifact: either going stale redoes both
     _drop_stale(manifest, accs + [a + ".cand" for a in accs])
@@ -947,7 +978,8 @@ def _batched_accelsearch(fftfiles, cfg, manifest=None, obs=None):
         from presto_tpu.apps.accelsearch import refine_and_write
         for nbins, files in _length_groups(
                 todo, lambda sz: sz // 8).items():
-            searcher, T = _survey_searcher(files[0], nbins, cfg)
+            searcher, T = _survey_searcher(files[0], nbins, cfg, wmax,
+                                           fhi)
             # memory budget ~1 GB of host spectra per batched call
             per = max(1, int(2 ** 30 // max(nbins * 8, 1)))
             for g0 in range(0, len(files), per):
@@ -964,8 +996,8 @@ def _batched_accelsearch(fftfiles, cfg, manifest=None, obs=None):
                 arts = []
                 for f, amps, raw in zip(chunk, amps_list, results):
                     refine_and_write(raw, amps, T, searcher, f[:-4],
-                                     cfg.zmax, quiet=True)
-                    acc = f[:-4] + "_ACCEL_%d" % cfg.zmax
+                                     cfg.zmax, wmax, quiet=True)
+                    acc = f[:-4] + tag
                     arts += [acc, acc + ".cand"]
                 _record(manifest, arts, "accel")
                 jaxtel.sample_live_buffers(obs)
@@ -1003,9 +1035,9 @@ def _finish_survey_stages(rawfiles, cfg, workdir, base, res, timer,
     # ---- 7. sift ------------------------------------------------------
     from presto_tpu.pipeline.sifting import sift_candidates
     accfiles = []
-    for (zmax, _nh, _sg, _flo) in cfg.all_passes:
-        accfiles += _stage(os.path.basename(base)
-                           + "_DM*_ACCEL_%d" % zmax, workdir)
+    for p in cfg.all_passes:
+        accfiles += _stage(os.path.basename(base) + "_DM*" + pass_tag(p),
+                           workdir)
     accfiles = sorted(set(accfiles))
     res.candfile = os.path.join(workdir, "cands_sifted.txt")
     cl = sift_candidates(accfiles, numdms_min=cfg.min_dm_hits,
@@ -1033,7 +1065,7 @@ def _finish_survey_stages(rawfiles, cfg, workdir, base, res, timer,
         cl, fold_top=cfg.fold_top, fold_sigma=cfg.fold_sigma,
         max_folds=cfg.max_folds,
         max_folds_per_pass=cfg.max_folds_per_pass,
-        pass_zmaxes=[z for (z, _nh, _sg, _flo) in cfg.all_passes],
+        pass_zmaxes=[pass_tag(p) for p in cfg.all_passes],
         policy=resolve_triage_policy(cfg.triage, workdir),
         accounting=accounting)
     tacct = accounting.get("triage")

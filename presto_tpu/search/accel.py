@@ -538,8 +538,7 @@ def _make_search_scanner(numharmstages, fracs_zinds, powcuts, slab, k,
     def slab_body(planes, start_col):
         """planes: [1 + n_harm_terms] source planes — planes[0] is the
         fundamental, planes[1 + fi] the source for harmonic term fi.
-        For the z-only search every entry aliases ONE buffer (free);
-        the jerk search passes per-subharmonic-w planes."""
+        For the z-only search every entry aliases ONE buffer (free)."""
         P = planes[0]
         cols = start_col + jnp.arange(slab, dtype=jnp.int32)
         acc = jax.lax.dynamic_slice(P, (0, start_col), (P.shape[0], slab))
@@ -662,8 +661,6 @@ def _make_search_scanner(numharmstages, fracs_zinds, powcuts, slab, k,
 
     scan_all = jax.jit(_scan_all_py)
     scan_all.body = _scan_all_py     # unjitted, for fused build+search
-    # jerk search: explicit per-subharmonic-w source planes
-    scan_all.planes = jax.jit(_scan_planes_py)
 
     @jax.jit
     def scan_many(Ps, start_cols):
@@ -812,7 +809,6 @@ class AccelSearch:
                 self._plb_hw_eff = hw_eff
         self._fn_cache = {}   # compiled build/scan fns (avoid re-jit)
         self._kern_dev = None  # device copy of the kernel bank (lazy)
-        self._w_banks = {0.0: self.kern}   # jerk-search kernel banks
         self.rlo = cfg.rlo if cfg.rlo > 0 else max(cfg.flo * T, 8.0)
         self.rhi = cfg.rhi if cfg.rhi > 0 else numbins - 1
         # numindep & powcut per stage (accel_utils.c:1629-1641)
@@ -857,8 +853,7 @@ class AccelSearch:
             startr += step
         return blocks
 
-    def build_plane(self, fft_pairs: np.ndarray,
-                    kern_pairs_dev=None):
+    def build_plane(self, fft_pairs: np.ndarray):
         """Fundamental F-Fdot plane P[numz, plane_numr] — a device
         array resident in HBM (host transfers of the multi-GB plane
         through the host<->TPU link would dominate the search time).
@@ -876,15 +871,13 @@ class AccelSearch:
         if not starts:
             # spectrum too short for one full block: empty plane
             return jnp.zeros((kern.numz, 0), dtype=jnp.float32)
-        if kern_pairs_dev is None:
-            kern_pairs_dev = self._kern_bank_dev()
         yp = self._build_plan_ns()
         key = ("build",) + yp.key
         self._build_plan = key
         if key not in self._fn_cache:
             self._fn_cache[key] = jax.jit(yp.build_body)
         return self._fn_cache[key](self._to_dev(fft_pairs),
-                                   kern_pairs_dev)
+                                   self._kern_bank_dev())
 
     def _kern_bank_dev(self):
         if self._kern_dev is None:   # one small upload, reused
@@ -895,7 +888,7 @@ class AccelSearch:
     @staticmethod
     def _to_dev(fft_pairs):
         if isinstance(fft_pairs, jax.Array):
-            return fft_pairs             # already uploaded (jerk loop)
+            return fft_pairs             # already uploaded
         return jnp.asarray(np.ascontiguousarray(fft_pairs))
 
     def _plane_geom(self):
@@ -981,8 +974,7 @@ class AccelSearch:
     def _chunk_slab_fn(self, g):
         """Per-chunk slab computation: [chunk, numdata] complex block
         windows -> [numz, chunk*uselen] slab in plane (z-major)
-        layout.  kern_use is an ARGUMENT (not a closure) so the jerk
-        search's per-w kernel banks share one compiled function; it is
+        layout.  kern_use is an ARGUMENT (not a closure): it is
         the complex FFT'd bank for the fft engine and the stage-layout
         conj bank (_kern_bank_z) for the mxu engine."""
         cfg, kern = self.cfg, self.kern
@@ -1208,15 +1200,9 @@ class AccelSearch:
                slab: int = 1 << 20) -> List[AccelCand]:
         """Run the full staged harmonic-summing search.
 
-        With cfg.wmax set this is the JERK search: one F-Fdot plane per
-        w on the ACCEL_DW grid (each with w-response kernels), searched
-        independently and merged — the reference jerk search's
-        (r, z, w) volume.  Harmonic summing reads each subharmonic
-        from the plane at its OWN grid w, w_sub = calc_required_w(
-        harm/numharm, w) — the per-subharmonic w kernels of modern
-        PRESTO's jerk search — via an HBM-budgeted device plane cache
-        (planes are built in |w| order so subharmonic planes usually
-        already exist; evicted ones are rebuilt).
+        With cfg.wmax set this is the JERK search over the (r, z, w)
+        volume of the ACCEL_DW w grid, built band by band with each
+        subharmonic read from its own w plane (search/jerk.py).
 
         The plane stays resident in HBM; the search region is processed
         in `slab`-column accumulator slabs (peak extra memory ~
@@ -1234,7 +1220,8 @@ class AccelSearch:
         """
         cfg = self.cfg
         if plane is None and cfg.wmax:
-            return self._search_jerk(fft_pairs, slab)
+            from presto_tpu.search import jerk
+            return jerk.volume(self).search_many(fft_pairs[None])[0]
         if plane is None:
             cs = self._search_fused(fft_pairs, slab,
                                     self._kern_bank_dev())
@@ -1243,190 +1230,10 @@ class AccelSearch:
             plane = self.build_plane(fft_pairs)
         return self._search_plane(plane, slab)
 
-    def _harm_fracs(self):
-        """Harmonic fractions in the scanner's term order — derived
-        from the SAME flattened _harm_fracs_and_zinds list the scanner
-        consumes, so the planes[1+fi] <-> fraction pairing cannot
-        drift."""
-        fz = _harm_fracs_and_zinds(self.cfg, self.cfg.numz)
-        return [harm / htot
-                for stage in fz for (harm, htot, _zi) in stage]
-
     def _collect_packed(self, packed, start_cols) -> List[AccelCand]:
         vals, cidx, zrow = _unpack_scan(packed)
         return self._dedup_sort(
             self._collect_group(vals, cidx, zrow, start_cols))
-
-    def _search_jerk(self, fft_pairs, slab: int) -> List[AccelCand]:
-        """The (r, z, w) jerk search over the ACCEL_DW w grid with
-        per-subharmonic-w source planes (see search() docstring)."""
-        cfg = self.cfg
-        fft_pairs = self._to_dev(fft_pairs)
-        fracs = self._harm_fracs()
-
-        # host-RAM budget for cached w kernel banks (a bank is
-        # numz*kmax*2 float32 ~ a few MB; a wmax=300 search uses 31
-        # fundamental banks plus subharmonic-w banks, and rebuilding
-        # one costs seconds of host quadrature — cache by bytes, not
-        # the old count-of-8 which thrashed past wmax=140)
-        bank_budget = int(os.environ.get(
-            "PRESTO_TPU_WBANK_BUDGET", str(512 * 2 ** 20)))
-
-        def bank_for(wg: float) -> AccelKernels:
-            bank = self._w_banks.get(wg)
-            if bank is None:
-                bank = AccelKernels.build(cfg, wg)
-                used = sum(b.kern_pairs.nbytes
-                           for b in self._w_banks.values())
-                if used + bank.kern_pairs.nbytes <= bank_budget:
-                    self._w_banks[wg] = bank
-            return bank
-
-        all_cands: List[AccelCand] = []
-
-        if not fracs:
-            # numharm == 1: no subharmonic reads — take the fused
-            # build+search dispatch per w (no resident plane at all)
-            for w in (float(x) for x in cfg.ws):
-                kern_dev = self._w_bank_dev(w, bank_for)
-                cs = self._search_fused(fft_pairs, slab, kern_dev)
-                if cs is None:
-                    cs = self._search_plane(
-                        self.build_plane(fft_pairs, kern_dev), slab)
-                for c in cs:
-                    c.w = w
-                    all_cands.append(c)
-            return self._merge_w_cands(all_cands)
-        return self._search_jerk_planes(fft_pairs, slab, fracs,
-                                        bank_for, all_cands)
-
-    def _w_bank_dev(self, wg: float, bank_for):
-        """Device FFT'd kernel bank for the w-plane grid value wg,
-        LRU-cached ACROSS search() calls (HBM-byte-budgeted,
-        PRESTO_TPU_WBANK_DEV_BUDGET, default 512 MB).  A steady-state
-        jerk survey re-searches many spectra with one config; without
-        this cache every search re-uploads ~1-3 MB per w bank through
-        the host link and re-FFTs it — measurable against the ~200 ms
-        per-w device work."""
-        cache = getattr(self, "_w_banks_dev_cache", None)
-        if cache is None:
-            cache = self._w_banks_dev_cache = {}
-        ent = cache.pop(wg, None)
-        if ent is None:
-            bank = bank_for(wg)
-            ent = _fft_kernel_bank_c(jnp.asarray(bank.kern_pairs),
-                                     bank.fftlen)
-            budget = int(os.environ.get(
-                "PRESTO_TPU_WBANK_DEV_BUDGET", str(512 * 2 ** 20)))
-            nbytes = int(np.prod(ent.shape)) * ent.dtype.itemsize
-            used = sum(int(np.prod(b.shape)) * b.dtype.itemsize
-                       for b in cache.values())
-            while cache and used + nbytes > budget:   # LRU: dicts
-                old = next(iter(cache))               # keep insert
-                used -= int(np.prod(cache[old].shape)) \
-                    * cache[old].dtype.itemsize       # order
-                del cache[old]
-        cache[wg] = ent               # (re)insert most-recent
-        return ent
-
-    def _search_jerk_planes(self, fft_pairs, slab, fracs, bank_for,
-                            all_cands):
-        """The numharm>1 jerk path: per-subharmonic-w source planes
-        over an HBM-budgeted LRU, with ALL w scans dispatched before
-        any host collection — jax dispatches are async, so the host
-        sync (the per-w np.asarray of round 4) was paying the
-        dispatch+sync floor once per w plane;
-        queueing every scan first and collecting afterwards pays it
-        once for the whole ws ladder (same float program, identical
-        candidates)."""
-        cfg = self.cfg
-
-        # Per-subharmonic-w source planes over an HBM-budgeted LRU.
-        # Planes in `keep` are the current scan's working set and are
-        # never evicted — at numharm=16 that is up to 5 distinct
-        # planes, the irreducible footprint of per-subharmonic reads.
-        plane_cache: dict = {}        # grid w -> device plane (LRU)
-        g = self._plane_geom()
-        plane_bytes = max(self.kern.numz * g.plane_numr * 4, 1) \
-            if g else 1
-        # cache budget = shared HBM constant minus the plane-build
-        # working set (the concat build holds plane + the per-chunk
-        # slabs + chunk intermediate concurrently — see
-        # _build_plan_ns), so the two budgets cannot stack past the
-        # device
-        build_ws = (self.kern.numz * g.body_numr * 4
-                    + CHUNK_BUDGET_BYTES) if g else 0
-        cache_budget = max(DEVICE_HBM_BYTES - build_ws - 2 * 2 ** 30,
-                           plane_bytes)
-        max_planes = max(1, int(cache_budget // plane_bytes))
-
-        def plane_for(wg: float, keep: set):
-            pl = plane_cache.pop(wg, None)
-            if pl is None:
-                # evict BEFORE building so peak residency stays at
-                # max_planes (+ the build's own working memory)
-                while len(plane_cache) >= max_planes:
-                    for old in list(plane_cache):   # LRU, spare keep
-                        if old not in keep:
-                            del plane_cache[old]
-                            break
-                    else:
-                        break
-                pl = self.build_plane(fft_pairs,
-                                      self._w_bank_dev(wg, bank_for))
-            plane_cache[wg] = pl      # (re)insert most-recent
-            return pl
-
-        # one slab plan for the whole loop: plane width is w-invariant
-        # (fftlen/uselen geometry is shared by every bank)
-        splan = self._slab_plan(g.plane_numr, slab) if g else None
-        if splan is None:
-            return []
-        slab_, k, scanner, start_cols = splan
-        scols = jnp.asarray(start_cols, dtype=jnp.int32)
-        # Queue w scans AHEAD of collection so the device runs back-
-        # to-back while the host decodes (collection = the sync that
-        # otherwise pays the dispatch floor once per w) — but
-        # with a BOUNDED in-flight window: queued executions keep
-        # their input planes alive regardless of host-side LRU
-        # eviction, so an unbounded queue would hold the whole ws
-        # ladder's planes at once and defeat the HBM budget.  A
-        # window of 2 (one collecting + one queued, the r4 e2e's
-        # one-ahead pipeline) captures the overlap at a bounded
-        # +1 working set of planes.
-        MAX_INFLIGHT = 2
-
-        def drain(pend, down_to):
-            while len(pend) > down_to:
-                w, packed = pend.pop(0)
-                for c in self._collect_packed(packed, start_cols):
-                    # the plane cell is the numharm-th harmonic: its
-                    # (r, z, w) all scale down to the fundamental
-                    c.w = w / c.numharm
-                    all_cands.append(c)
-
-        pend = []
-        for w in sorted((float(x) for x in cfg.ws), key=abs):
-            wsubs = [calc_required_w(f, w) for f in fracs]
-            keep = set(wsubs) | {w}
-            pl = plane_for(w, keep)
-            subs = [plane_for(wg, keep) for wg in wsubs]
-            pend.append((w, scanner.planes(tuple([pl] + subs),
-                                           scols)))
-            drain(pend, MAX_INFLIGHT - 1)
-        drain(pend, 0)
-        return self._merge_w_cands(all_cands)
-
-    @staticmethod
-    def _merge_w_cands(all_cands: List[AccelCand]) -> List[AccelCand]:
-        """Same (numharm, r) found in neighboring w planes: keep the
-        strongest (the volume's local max)."""
-        best = {}
-        for c in sorted(all_cands, key=lambda c: -c.sigma):
-            key = (c.numharm, c.r)
-            if key not in best:
-                best[key] = c
-        return sorted(best.values(), key=lambda c: (-c.sigma, c.r))
 
     def _search_fused(self, fft_pairs, slab: int,
                       kern_dev) -> Optional[List[AccelCand]]:
@@ -1629,10 +1436,9 @@ class AccelSearch:
         if nd == 0:
             return []
         if cfg.wmax:
-            # jerk searches never take the batched path: go straight
-            # to the per-DM loop (no wasted priming plane build)
-            return [self.search(batch[i], slab=slab)
-                    for i in range(nd)]
+            # the banded (r, z, w) volume, a chunk's trials at a time
+            from presto_tpu.search import jerk
+            return jerk.volume(self).search_many(batch, obs=obs)
         # the plane geometry comes from the build program's shape
         # alone: every DM (the first included) runs through the
         # grouped build+scan programs, so a batch compiles those two

@@ -89,7 +89,8 @@ def _term_geom(harm: int, htot: int, zinds: np.ndarray,
 
 def make_stage_reducer(numharmstages, fracs_zinds, slab: int,
                        numz: int, plane_numr: int,
-                       interpret: bool = False, tile: int = None):
+                       interpret: bool = False, tile: int = None,
+                       shifted: bool = False):
     """Build the pallas stage reducer.
 
     Returns f(P, start_cols) -> (colmax f32, colz i32), each
@@ -99,6 +100,13 @@ def make_stage_reducer(numharmstages, fracs_zinds, slab: int,
 
     Requires slab % tile == 0, start_cols % tile == 0, and P padded
     to ceil(numz/8)*8 rows (zero rows below; `pad_rows` below).
+
+    ``shifted`` (the banded jerk volume, search/jerk.py): f(P, subs,
+    start_cols, shifts) reads harmonic term fi from its own plane
+    subs[fi].  Plane columns are absolute columns less an origin:
+    shifts[0] for P, shifts[1 + fi] for subs[fi] (multiples of 128);
+    then start_cols + shifts[0] must be multiples of tile, and each
+    subs[fi] must hold its term's source columns plus PLANE_PAD.
 
     `tile` (default TILE) is threaded explicitly through the whole
     build — module state is never consulted or mutated, so concurrent
@@ -130,7 +138,14 @@ def make_stage_reducer(numharmstages, fracs_zinds, slab: int,
         onehots.append(jnp.asarray(
             np.concatenate([oh, oh, oh], axis=1).astype(jnp.bfloat16)))
 
-    def kernel(start_cols_ref, P_ref, *refs):
+    def kernel(start_cols_ref, *refs):
+        if shifted:
+            shifts_ref, P_ref = refs[0], refs[1]
+            Q_refs = refs[2:2 + nterms]
+            refs = refs[2 + nterms:]
+        else:
+            P_ref = refs[0]
+            refs = refs[1:]
         oh_refs = refs[:nterms]
         colmax_ref, colz_ref = refs[nterms], refs[nterms + 1]
         acc_ref = refs[nterms + 2]
@@ -155,10 +170,16 @@ def make_stage_reducer(numharmstages, fracs_zinds, slab: int,
         def term_dma(fi):
             harm, htot, _z = terms[fi]
             rows, win = geom[fi]
-            cs = (j0 // htot) * harm
+            if shifted:
+                cs = ((j0 + shifts_ref[0]) // htot) * harm \
+                    - shifts_ref[1 + fi]
+                src = Q_refs[fi]
+            else:
+                cs = (j0 // htot) * harm
+                src = P_ref
             off = cs % 128
             return pltpu.make_async_copy(
-                P_ref.at[pl.ds(0, rows),
+                src.at[pl.ds(0, rows),
                          pl.ds(pl.multiple_of(cs - off, 128), win)],
                 win_refs[1 + fi].at[bank], sems.at[1 + fi, bank]), off
 
@@ -218,13 +239,14 @@ def make_stage_reducer(numharmstages, fracs_zinds, slab: int,
                 fi += 1
             collect(stage)
 
-    @jax.jit
-    def reduce_stages(P, start_cols):
+    nplanes = 1 + nterms if shifted else 1
+
+    def call(P, start_cols, subs=(), shifts=None):
         nslabs = start_cols.shape[0]
         gs = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2 if shifted else 1,
             grid=(nslabs, ntiles),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] +   # P (HBM)
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * nplanes +
                      [pl.BlockSpec(memory_space=pltpu.VMEM)] * nterms,
             out_specs=[
                 pl.BlockSpec((1, nstages, tile),
@@ -252,7 +274,18 @@ def make_stage_reducer(numharmstages, fracs_zinds, slab: int,
                                      jnp.int32),
             ],
             interpret=interpret,
-        )(start_cols, P, *onehots)
+        )(*((start_cols, shifts, P) + tuple(subs) if shifted
+            else (start_cols, P)), *onehots)
+
+    if shifted:
+        @jax.jit
+        def reduce_shifted(P, subs, start_cols, shifts):
+            return call(P, start_cols, subs, shifts)
+        return reduce_shifted
+
+    @jax.jit
+    def reduce_stages(P, start_cols):
+        return call(P, start_cols)
 
     return reduce_stages
 

@@ -78,7 +78,7 @@ def _pass_zmaxes(config: dict) -> List[int]:
     try:
         from presto_tpu.pipeline.survey import SurveyConfig
         cfg = SurveyConfig(**dict(config or {}))
-        return [int(z) for (z, _nh, _sg, _flo) in cfg.all_passes]
+        return [int(p[0]) for p in cfg.all_passes]
     except Exception:
         return [int((config or {}).get("zmax", 0))]
 

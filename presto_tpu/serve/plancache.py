@@ -277,7 +277,11 @@ def accel_plan_key(acfg, T: float, numbins: int) -> PlanKey:
                    dtype="float32", dm_block=(),
                    zmax=int(acfg.zmax), numharm=int(acfg.numharm),
                    extra=(float(acfg.sigma), float(acfg.flo),
-                          round(float(T), 9)))
+                          round(float(T), 9))
+                   # a jerk pass (-wmax) or a band (-rlo/-rhi) is
+                   # another plan; a plain pass keeps its old key
+                   + ((int(acfg.wmax), float(acfg.rlo), float(acfg.rhi))
+                      if acfg.wmax or acfg.rlo or acfg.rhi else ()))
 
 
 class SearcherProvider:

@@ -66,6 +66,53 @@ def test_pallas_reducer_matches_numpy(numharm):
     np.testing.assert_array_equal(got_z, want_z)
 
 
+@pytest.mark.parametrize("numharm", [4, 8])
+def test_shifted_reducer_reads_each_term_from_its_own_plane(numharm):
+    """The banded jerk volume's form (search/jerk.py): harmonic term
+    fi read from its own plane subs[fi], every plane's columns being
+    absolute columns less its origin."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(1)
+    cfg = AccelConfig(zmax=20, numharm=numharm)
+    numz, nstages = cfg.numz, cfg.numharmstages
+    fz = _harm_fracs_and_zinds(cfg, numz)
+    terms = [(h, t, np.asarray(zi)) for st in fz for (h, t, zi) in st]
+    slab, p0 = 2 * TILE, 37 * TILE          # absolute start of the slab
+    o0 = p0 - 3 * 128                       # the fundamental's origin
+    rows = pad_rows(numz)
+    P = rng.random((rows, slab + 3 * 128)).astype(np.float32)
+    P[numz:] = 0.0
+    subs, origins = [], [o0]
+    for h, t, _zi in terms:
+        lo = (p0 * h // t) // 256 * 256 - 128   # 128-multiple origin
+        Q = rng.random((rows, slab * h // t + 384 + PLANE_PAD)
+                       ).astype(np.float32)
+        Q[numz:] = 0.0
+        subs.append(Q)
+        origins.append(lo)
+    reducer = make_stage_reducer(nstages, fz, slab, numz, 0,
+                                 interpret=True, shifted=True)
+    got_max, got_z = (np.asarray(a) for a in reducer(
+        jnp.asarray(P), tuple(jnp.asarray(q) for q in subs),
+        jnp.asarray([p0 - o0], np.int32),
+        jnp.asarray(origins, np.int32)))
+    cols = p0 + np.arange(slab)
+    acc = P[:numz, cols - o0].astype(np.float64)
+    want_max = [acc.max(0)]
+    want_z = [acc.argmax(0)]
+    fi = 0
+    for stage in range(1, nstages):
+        for _ in range(1 << (stage - 1)):
+            h, t, zi = terms[fi]
+            src = (cols // t) * h + ((cols % t) * h + (t >> 1)) // t
+            acc = acc + subs[fi][zi[:, None], src[None] - origins[1 + fi]]
+            fi += 1
+        want_max.append(acc.max(0))
+        want_z.append(acc.argmax(0))
+    np.testing.assert_allclose(got_max[0], np.stack(want_max), rtol=1e-6)
+    np.testing.assert_array_equal(got_z[0], np.stack(want_z))
+
+
 def test_plane_builder_matches_mxu_engine():
     """search/build_pallas.py (the direct-plane build kernel) must
     agree with the XLA factored-DFT engine it mirrors (interpret

@@ -236,9 +236,9 @@ def test_seam_fft_search_one_searcher_per_pass(tmp_path, monkeypatch):
     made = []
     real = survey._searcher_for
 
-    def counting(cfg, T, nbins):
+    def counting(cfg, T, nbins, *jerk):
         made.append(cfg.zmax)
-        return real(cfg, T, nbins)
+        return real(cfg, T, nbins, *jerk)
 
     monkeypatch.setattr(survey, "_searcher_for", counting)
     seam = StageSeam(str(tmp_path), durable=False)

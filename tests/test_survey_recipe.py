@@ -44,7 +44,9 @@ def test_palfa_recipe_one_command(tmp_path):
 def test_recipe_expansion():
     """Recipe -> SurveyConfig policy mapping (fast check)."""
     from presto_tpu.pipeline.recipes import get_recipe, RECIPES
-    assert set(RECIPES) == {"palfa", "gbncc", "gbt350drift"}
+    assert set(RECIPES) == {"palfa", "gbncc", "gbt350drift", "ter5"}
+    ter5 = get_recipe("ter5").to_config(230.0, 246.0)
+    assert ter5.all_passes == ((0, 16, 2.0, 2.0), (200, 8, 3.0, 1.0, 300))
     drift = get_recipe("gbt350drift").to_config(0.0, 90.0)
     # per-pass flo: lo_accel_flo=2.0 / hi_accel_flo=1.0
     # (GBT350_drift_search.py:30-33)

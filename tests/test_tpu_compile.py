@@ -75,6 +75,28 @@ def test_stage_reducer_compiles(one_chip, zmax, numharm):
     assert _has_kernel(compiled)
 
 
+def test_shifted_stage_reducer_compiles(one_chip):
+    # the banded jerk volume's scan at the ter5 cell's width: zmax 200,
+    # numharm 8, one 2^19-column piece, each term from its own plane
+    from presto_tpu.search import accel, accel_pallas as ap
+    cfg = accel.AccelConfig(zmax=200, numharm=8)
+    fz = accel._harm_fracs_and_zinds(cfg, cfg.numz)
+    slab = 1 << 19
+    tile = ap.pick_tile(fz, cfg.numz, slab)
+    assert tile, "no reducer tile fits VMEM at zmax=200"
+    reducer = ap.make_stage_reducer(cfg.numharmstages, fz, slab,
+                                    cfg.numz, 0, tile=tile, shifted=True)
+    numz_pad = ap.pad_rows(cfg.numz)
+    terms = [(h, t) for st in fz for (h, t, _zi) in st]
+    subs = tuple(_spec((numz_pad, slab * h // t + 2 * 7424), jnp.float32,
+                       one_chip) for h, t in terms)
+    compiled = reducer.lower(
+        _spec((numz_pad, slab + 2 * 7424), jnp.float32, one_chip), subs,
+        _spec((1,), jnp.int32, one_chip),
+        _spec((1 + len(terms),), jnp.int32, one_chip)).compile()
+    assert _has_kernel(compiled)
+
+
 @pytest.mark.parametrize("zmax", [200, 50])
 def test_plane_builder_compiles(one_chip, zmax):
     from presto_tpu.search import build_pallas as bp
