@@ -14,6 +14,7 @@
 //   pt_unpack_bits        1/2/4-bit -> uint8 (MSB-first within byte)
 //   pt_unpack_to_float    1/2/4/8-bit -> float32, fused
 //   pt_decode_spectra     filterbank block: unpack + nifs-sum + flip
+//   pt_decode_spectra_chan  the same, written channel-major
 //   pt_decode_subint      PSRFITS subint: unpack + zero_off + scale/
 //                         offset + poln select/sum + weights + flip
 //   pt_feeder_*           background prefetching block reader
@@ -109,37 +110,33 @@ void pt_unpack_to_float(const uint8_t *raw, int64_t nbytes, int nbits,
 // nbits in {1,2,4,8}; 16/32-bit stay on the NumPy path (cheap there).
 // ---------------------------------------------------------------------------
 
+// One spectrum's nifs IFs unpacked and summed into op[0:nchan], in
+// recorded channel order; tmp holds nifs*nchan floats when nifs > 1.
+static void decode_one(const uint8_t *rp, int64_t spec_bytes, int nifs,
+                       int nchan, int nbits, float *tmp, float *op) {
+    if (nifs == 1) {
+        pt_unpack_to_float(rp, spec_bytes, nbits, op);
+        return;
+    }
+    pt_unpack_to_float(rp, spec_bytes, nbits, tmp);
+    memcpy(op, tmp, sizeof(float) * nchan);
+    for (int p = 1; p < nifs; ++p) {
+        const float *vp = tmp + (int64_t)p * nchan;
+        for (int c = 0; c < nchan; ++c)
+            op[c] += vp[c];
+    }
+}
+
 void pt_decode_spectra(const uint8_t *raw, int64_t nspec, int nifs,
                        int nchan, int nbits, int flip, float *out) {
     const int64_t vals_per_spec = (int64_t)nifs * nchan;
     const int64_t spec_bytes = vals_per_spec * nbits / 8;
-    float *tmp = (nifs > 1 || nbits < 8)
-                     ? (float *)malloc(sizeof(float) * vals_per_spec)
-                     : NULL;
+    float *tmp = nifs > 1 ? (float *)malloc(sizeof(float) * vals_per_spec)
+                          : NULL;
     for (int64_t s = 0; s < nspec; ++s) {
-        const uint8_t *rp = raw + s * spec_bytes;
         float *op = out + s * nchan;
-        const float *vals;
-        if (nbits == 8 && nifs == 1) {
-            // decode straight into the output row
-            for (int c = 0; c < nchan; ++c)
-                op[c] = (float)rp[c];
-            vals = op;
-        } else {
-            pt_unpack_to_float(rp, spec_bytes, nbits, tmp);
-            vals = tmp;
-        }
-        if (nifs > 1) {
-            for (int c = 0; c < nchan; ++c)
-                op[c] = vals[c];
-            for (int p = 1; p < nifs; ++p) {
-                const float *vp = vals + (int64_t)p * nchan;
-                for (int c = 0; c < nchan; ++c)
-                    op[c] += vp[c];
-            }
-        } else if (vals != op) {
-            memcpy(op, vals, sizeof(float) * nchan);
-        }
+        decode_one(raw + s * spec_bytes, spec_bytes, nifs, nchan, nbits,
+                   tmp, op);
         if (flip) {
             for (int c = 0; c < nchan / 2; ++c) {
                 float t = op[c];
@@ -148,6 +145,69 @@ void pt_decode_spectra(const uint8_t *raw, int64_t nspec, int nifs,
             }
         }
     }
+    free(tmp);
+}
+
+// ---------------------------------------------------------------------------
+// Channel-major filterbank block decode: the values of pt_decode_spectra
+// written as out[c * nspec + s], i.e. float32 [nchan, nspec] (a Fortran-
+// order [nspec, nchan] block), so the consumers' [C, T] view of the block
+// needs no transposing copy.  The flip maps raw channel c to row
+// nchan-1-c.  Tiles of TILE_S spectra x TILE_C channels go through a
+// small scratch tile: each raw row is read in whole cache lines and each
+// output row written in TILE_S-float runs.  8-bit single-IF data is read
+// straight from the raw bytes; other widths decode TILE_S spectra into a
+// time-major band first.
+// ---------------------------------------------------------------------------
+
+static const int TILE_S = 256;
+static const int TILE_C = 32;
+
+extern "C++" {
+template <typename T>
+static void transpose_band(const T *src, int64_t src_stride, int ns,
+                           int nchan, int flip, int64_t nspec, int64_t s0,
+                           float *out) {
+    float tile[TILE_C * TILE_S];
+    for (int c0 = 0; c0 < nchan; c0 += TILE_C) {
+        const int nc = nchan - c0 < TILE_C ? nchan - c0 : TILE_C;
+        for (int s = 0; s < ns; ++s) {
+            const T *rp = src + s * src_stride + c0;
+            for (int c = 0; c < nc; ++c)
+                tile[c * TILE_S + s] = (float)rp[c];
+        }
+        for (int c = 0; c < nc; ++c) {
+            const int64_t row = flip ? nchan - 1 - (c0 + c) : c0 + c;
+            memcpy(out + row * nspec + s0, tile + c * TILE_S,
+                   sizeof(float) * ns);
+        }
+    }
+}
+}  // extern "C++"
+
+void pt_decode_spectra_chan(const uint8_t *raw, int64_t nspec, int nifs,
+                            int nchan, int nbits, int flip, float *out) {
+    const int64_t vals_per_spec = (int64_t)nifs * nchan;
+    const int64_t spec_bytes = vals_per_spec * nbits / 8;
+    const bool direct = nbits == 8 && nifs == 1;
+    float *band = direct ? NULL
+                         : (float *)malloc(sizeof(float) * TILE_S * nchan);
+    float *tmp = nifs > 1 ? (float *)malloc(sizeof(float) * vals_per_spec)
+                          : NULL;
+    for (int64_t s0 = 0; s0 < nspec; s0 += TILE_S) {
+        const int ns = nspec - s0 < TILE_S ? (int)(nspec - s0) : TILE_S;
+        const uint8_t *rp = raw + s0 * spec_bytes;
+        if (direct) {
+            transpose_band(rp, spec_bytes, ns, nchan, flip, nspec, s0, out);
+            continue;
+        }
+        for (int s = 0; s < ns; ++s)
+            decode_one(rp + s * spec_bytes, spec_bytes, nifs, nchan, nbits,
+                       tmp, band + (int64_t)s * nchan);
+        transpose_band(band, (int64_t)nchan, ns, nchan, flip, nspec, s0,
+                       out);
+    }
+    free(band);
     free(tmp);
 }
 

@@ -111,8 +111,9 @@ class BlockPrep:
 
     def __init__(self, nchan, dt, args, mask=None, padvals=None,
                  ignore=None):
-        from presto_tpu.ops.clipping import (clip_times, remove_zerodm,
-                                             mask_block)
+        from presto_tpu.ops.clipping import (channel_means, clip_times,
+                                             remove_zerodm, mask_block)
+        self._channel_means = channel_means
         self._clip_times = clip_times
         self._remove_zerodm = remove_zerodm
         self._mask_block = mask_block
@@ -130,9 +131,12 @@ class BlockPrep:
         self._clip_state = None
 
     def __call__(self, block, start_spectra):
-        """block: [T, C] float32 (ascending freq); returns same shape.
-        A block that owns its writable data (a decoder's fresh output)
-        is clipped in place; a view or read-only data is copied first.
+        """block: [T, C] float32 (ascending freq); returns the same
+        shape in the block's memory layout, so a channel-major block
+        (the SIGPROC decoder's) stays channel-major and its ``.T`` needs
+        no copy.  A block that owns its writable data (a decoder's fresh
+        output) is clipped in place; a view or read-only data is copied
+        first.
         Timed as an ``ingest:prep`` span of the process default
         observability handle, which also counts the clipped blocks by
         path and the rows replaced."""
@@ -153,7 +157,7 @@ class BlockPrep:
         if self.clip > 0:
             inplace = block.flags.writeable and block.flags.owndata
             if not inplace:
-                block = block.copy()
+                block = block.copy(order="K")
             block, nclip, self._clip_state = self._clip_times(
                 block, self.clip, self._clip_state, out=block)
             _note_clip(obs, "inplace" if inplace else "copy", nclip)
@@ -163,7 +167,7 @@ class BlockPrep:
         if self.runavg:
             # per-channel block-mean subtraction (the reference's
             # run_avg in read_PRESTO_subbands, prepsubband.c:838-846)
-            block = block - block.mean(axis=0, keepdims=True)
+            block = block - self._channel_means(block)
         if self.ignore is not None:
             block[:, self.ignore] = 0.0
         return block

@@ -48,7 +48,7 @@ def main(argv=None):
             while done < nout:
                 n = min(nblk, nout - done)
                 raw = fb.read_spectra(done * args.dsfact,
-                                      n * args.dsfact)
+                                      n * args.dsfact, chan_major=False)
                 d = raw.reshape(n, args.dsfact,
                                 hdr.nchans).mean(axis=1)
                 if hdr.foff < 0:     # disk order is descending freq

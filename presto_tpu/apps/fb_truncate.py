@@ -42,7 +42,8 @@ def truncate(inpath: str, outpath: str, tlo: float = 0.0,
         with open(outpath, "wb") as f:
             sigproc.write_filterbank_header(out_hdr, f)
             for start in range(s0, s1, block):
-                blk = fb.read_spectra(start, min(block, s1 - start))
+                blk = fb.read_spectra(start, min(block, s1 - start),
+                                      chan_major=False)
                 blk = blk[:, clo:chi]
                 arr = blk[:, ::-1] if h.foff < 0 else blk
                 sigproc.pack_bits(

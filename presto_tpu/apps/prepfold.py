@@ -446,7 +446,8 @@ def fold_raw(args, f, fd, fdd):
         if nread < hdr.N:
             block = prep(fb.read_spectra(nread, blocklen), nread)
         else:
-            block = np.zeros((blocklen, nchan), dtype=np.float32)
+            block = np.zeros((blocklen, nchan), dtype=np.float32,
+                             order="F")
         cur = jnp.asarray(np.ascontiguousarray(block.T))
         if prev is not None:
             # stays on device: one download at the end

@@ -317,7 +317,8 @@ def run(args):
                          else fb.read_spectra(nread, blocklen))
                 block = prep(block, nread)
             else:
-                block = np.zeros((blocklen, nchan), dtype=np.float32)
+                block = np.zeros((blocklen, nchan), dtype=np.float32,
+                                 order="F")
             yield nread, np.ascontiguousarray(block.T)
             nread += blocklen
 
@@ -564,7 +565,8 @@ def _dedisperse_rows(s: _Setup, args, rows):
             block = fb.read_spectra(nread, blocklen)
             block = prep(block, nread)
         else:
-            block = np.zeros((blocklen, s.nchan), dtype=np.float32)
+            block = np.zeros((blocklen, s.nchan), dtype=np.float32,
+                             order="F")
         cur = jnp.asarray(np.ascontiguousarray(block.T))
         if prev_raw is not None:
             if prev_sub is None:

@@ -83,6 +83,8 @@ def _load():
     lib.pt_unpack_bits.argtypes = [u8p, i64, i32, u8p]
     lib.pt_unpack_to_float.argtypes = [u8p, i64, i32, f32p]
     lib.pt_decode_spectra.argtypes = [u8p, i64, i32, i32, i32, i32, f32p]
+    lib.pt_decode_spectra_chan.argtypes = [u8p, i64, i32, i32, i32, i32,
+                                           f32p]
     lib.pt_decode_subint.argtypes = [u8p, i64, i32, i32, i32,
                                      ctypes.c_float, f32p, f32p, f32p,
                                      i32, i32, f32p]
@@ -122,8 +124,13 @@ def unpack_bits(raw: np.ndarray, nbits: int) -> Optional[np.ndarray]:
 
 
 def decode_spectra(raw: np.ndarray, nspec: int, nifs: int, nchan: int,
-                   nbits: int, flip: bool) -> Optional[np.ndarray]:
-    """Fused filterbank block decode -> float32 [nspec, nchan]."""
+                   nbits: int, flip: bool,
+                   chan_major: bool = True) -> Optional[np.ndarray]:
+    """Fused filterbank block decode -> float32 [nspec, nchan].
+
+    chan_major: the block's memory is channel-major (Fortran order, so
+    its ``.T`` is a C-contiguous [nchan, nspec]); False gives the
+    time-major (C order) block.  Either way the array owns its data."""
     lib = _load()
     if lib is None or nbits not in (1, 2, 4, 8):
         return None
@@ -132,9 +139,14 @@ def decode_spectra(raw: np.ndarray, nspec: int, nifs: int, nchan: int,
         return None
     if (nifs * nchan * nbits) % 8 != 0:
         return None      # spectra not byte-aligned; NumPy path handles
-    out = np.empty((nspec, nchan), np.float32)
-    lib.pt_decode_spectra(_u8ptr(raw), nspec, nifs, nchan, nbits,
-                          int(flip), _f32ptr(out))
+    if chan_major:
+        out = np.empty((nspec, nchan), np.float32, order="F")
+        decode = lib.pt_decode_spectra_chan
+    else:
+        out = np.empty((nspec, nchan), np.float32)
+        decode = lib.pt_decode_spectra
+    decode(_u8ptr(raw), nspec, nifs, nchan, nbits, int(flip),
+           _f32ptr(out))
     return out
 
 

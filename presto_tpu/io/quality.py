@@ -218,7 +218,7 @@ def scrub_nonfinite(block: np.ndarray, start: int,
     if not bad.any():
         return block
     if not block.flags.writeable:
-        block = block.copy()
+        block = block.copy(order="K")
         bad = ~np.isfinite(block)
     nbad = int(bad.sum())
     block[bad] = padval

@@ -232,25 +232,47 @@ def pack_bits(data: np.ndarray, nbits: int) -> np.ndarray:
 
 
 def decode_spectra_block(hdr: FilterbankHeader, raw: np.ndarray,
-                         nspec: int) -> np.ndarray:
+                         nspec: int, chan_major: bool = True) -> np.ndarray:
     """Packed filterbank bytes -> [nspec, nchans] float32, channels in
-    ASCENDING frequency order.  The one decode sequence shared by the
-    file reader, the prefetched feeder path, and the live socket /
-    file-tail producers (presto_tpu/stream/source.py): native decoder
-    when available, numpy unpack + IF-sum + descending-band flip
-    otherwise.  Timed as an ``ingest:decode`` span of the process
-    default observability handle."""
-    with resolve_obs(None).span("ingest:decode"):
+    ASCENDING frequency order, in a fresh array that owns its data.
+    The shape is [T, C]; the memory is channel-major (Fortran order),
+    so the dedispersion consumers' ``block.T`` is already a contiguous
+    [C, T].  Callers that serialize time-major bytes pass
+    ``chan_major=False`` for a C-order block.  The one decode sequence
+    shared by the file reader, the prefetched feeder path, and the live
+    socket / file-tail producers (presto_tpu/stream/source.py): native
+    decoder when available, numpy unpack + IF-sum + descending-band
+    flip otherwise.  Timed as an ``ingest:decode`` span of the process
+    default observability handle, which counts the blocks by layout."""
+    obs = resolve_obs(None)
+    with obs.span("ingest:decode"):
         arr = native.decode_spectra(raw, nspec, hdr.nifs, hdr.nchans,
-                                    hdr.nbits, hdr.foff < 0)
+                                    hdr.nbits, hdr.foff < 0, chan_major)
         if arr is None:
-            vals = unpack_bits(raw, hdr.nbits)
-            arr = vals.astype(np.float32).reshape(nspec, hdr.nifs,
-                                                  hdr.nchans)
+            vals = unpack_bits(raw, hdr.nbits).astype(np.float32,
+                                                      copy=False)
+            arr = vals.reshape(nspec, hdr.nifs, hdr.nchans)
             arr = arr.sum(axis=1) if hdr.nifs > 1 else arr[:, 0, :]
             if hdr.foff < 0:
-                arr = np.ascontiguousarray(arr[:, ::-1])
+                arr = arr[:, ::-1]
+            arr = np.array(arr, order="F" if chan_major else "C")
+    if obs.enabled:
+        obs.metrics.counter(
+            "ingest_decode_blocks_total",
+            "Filterbank blocks decoded, by memory layout",
+            ("layout",)).labels(
+                layout="chan" if chan_major else "time").inc()
     return arr
+
+
+def _zero_padded(arr: np.ndarray, count: int,
+                 chan_major: bool = True) -> np.ndarray:
+    """``arr``'s spectra followed by zero spectra up to ``count``, in
+    the asked memory layout."""
+    out = np.zeros((count, arr.shape[1]), np.float32,
+                   order="F" if chan_major else "C")
+    out[:len(arr)] = arr
+    return out
 
 
 class FilterbankFile:
@@ -259,7 +281,9 @@ class FilterbankFile:
     read_spectra() returns float32 [nsamp, nchans] with channels in
     ASCENDING frequency order (flipping if foff < 0), the order the
     dedispersion ops expect — the reference does the same flip inside
-    its readers (get_filterbank_rawblock, sigproc_fb.c:419-).
+    its readers (get_filterbank_rawblock, sigproc_fb.c:419-).  The
+    shape is [T, C] and the memory channel-major unless the caller
+    asks ``chan_major=False`` (see decode_spectra_block).
     """
 
     def __init__(self, path: str, quarantine: bool = True):
@@ -303,8 +327,11 @@ class FilterbankFile:
         """
         return 2400
 
-    def read_spectra(self, start: int, count: int) -> np.ndarray:
+    def read_spectra(self, start: int, count: int,
+                     chan_major: bool = True) -> np.ndarray:
         """Read `count` spectra starting at `start`; zero-pad past EOF.
+        [count, nchans], channel-major memory unless ``chan_major`` is
+        False (decode_spectra_block).
 
         Short reads (the file shrank after open — a writer died or the
         volume went away) are quarantined: the missing tail is recorded
@@ -320,12 +347,11 @@ class FilterbankFile:
         if got < navail:
             raw = raw[:got * bps]
             self.quality.add(start + got, start + navail, "short-read")
-        arr = self._decode_raw(raw, got)
+        arr = self._decode_raw(raw, got, chan_major)
         arr = self._scrub(arr, start, got)
         if got < count:
-            pad = np.zeros((count - got, hdr.nchans), dtype=np.float32)
-            arr = np.concatenate([arr, pad], axis=0)
-        return np.ascontiguousarray(arr)
+            arr = _zero_padded(arr, count, chan_major)
+        return arr
 
     def _scrub(self, arr: np.ndarray, start: int,
                nspec: int) -> np.ndarray:
@@ -340,11 +366,12 @@ class FilterbankFile:
         record_zero_runs(arr[:nspec], start, self.quality)
         return arr
 
-    def _decode_raw(self, raw: np.ndarray, nspec: int) -> np.ndarray:
+    def _decode_raw(self, raw: np.ndarray, nspec: int,
+                    chan_major: bool = True) -> np.ndarray:
         """Packed bytes -> [nspec, nchans] float32 ascending (the ONE
         decode sequence shared by the random-access and prefetched
         read paths)."""
-        return decode_spectra_block(self.header, raw, nspec)
+        return decode_spectra_block(self.header, raw, nspec, chan_major)
 
     def iter_blocks(self, block_size: int,
                     start: int = 0) -> Iterator[np.ndarray]:
@@ -379,9 +406,7 @@ class FilterbankFile:
                 arr = self._decode_raw(raw[:nspec * bps], nspec)
                 arr = self._scrub(arr, start + delivered, nspec)
                 if nspec < block_size:
-                    arr = np.concatenate(
-                        [arr, np.zeros((block_size - nspec,
-                                        hdr.nchans), np.float32)])
+                    arr = _zero_padded(arr, block_size)
                 delivered += nspec
                 yield arr
         finally:
@@ -454,8 +479,11 @@ class FilterbankSet:
     def ptsperblk(self) -> int:
         return 2400              # see FilterbankFile.ptsperblk
 
-    def read_spectra(self, start: int, count: int) -> np.ndarray:
-        out = np.zeros((count, self.header.nchans), dtype=np.float32)
+    def read_spectra(self, start: int, count: int,
+                     chan_major: bool = True) -> np.ndarray:
+        """FilterbankFile.read_spectra over the stitched files."""
+        out = np.zeros((count, self.header.nchans), dtype=np.float32,
+                       order="F" if chan_major else "C")
         got = 0
         while got < count:
             pos = start + got
@@ -467,7 +495,7 @@ class FilterbankSet:
             if local >= fb.header.N:
                 break             # past the last file: stay zero-padded
             n = min(count - got, fb.header.N - local)
-            out[got:got + n] = fb.read_spectra(local, n)
+            out[got:got + n] = fb.read_spectra(local, n, chan_major)
             got += n
         return out
 

@@ -1,4 +1,4 @@
-"""obs-coverage: the instrumentation-coverage contract (20 checks).
+"""obs-coverage: the instrumentation-coverage contract (22 checks).
 
 Formerly ``tools/obs_lint.py`` (a thin shim remains there for the
 historical entry point); now the fifth presto-lint family.  The
@@ -109,7 +109,12 @@ code path cannot ship silently:
      parent catalogs) — triage decides which candidates are never
      folded, so every learned selection, heuristic degrade
      (missing/corrupt weights), and calibration run must land on
-     telemetry a post-mortem can replay.
+     telemetry a post-mortem can replay;
+  21. the jerk volume (search/jerk.py + apps/accelsearch.py):
+     JERK_SPANS / JERK_METRICS pinned BOTH directions;
+  22. the ingest layer (io/ + apps/): INGEST_METRICS, the decode-layout
+     and clip-path block counters the ingest per-layer readings rest
+     on, pinned BOTH directions.
 
 Run via tools/presto_lint.py (exit-1 CLI over every family), the
 legacy tools/obs_lint.py shim, or tests/test_obs_lint.py.
@@ -1084,6 +1089,24 @@ def lint(root: Optional[str] = None) -> List[str]:
                 name, "listed in obs/taxonomy.JERK_METRICS but never "
                 "registered" if name in taxonomy.JERK_METRICS
                 else "not registered in obs/taxonomy.JERK_METRICS"))
+
+    # 22. the ingest layer (io/ + apps/): INGEST_METRICS pinned BOTH
+    # directions
+    in_metrics = set()
+    for rel, src in _tree_sources(root, "presto_tpu/io",
+                                  "presto_tpu/apps").items():
+        in_metrics |= {m for m in METRIC_RE.findall(src)
+                       if m.startswith(("ingest_decode_", "ingest_clip"))}
+    for name in sorted(taxonomy.INGEST_METRICS - taxonomy.METRICS):
+        problems.append(
+            "obs/taxonomy.py: INGEST_METRICS lists %r which is not in "
+            "METRICS" % name)
+    for name in sorted(taxonomy.INGEST_METRICS ^ in_metrics):
+        problems.append(
+            "ingest layer: metric %r is %s" % (
+                name, "listed in obs/taxonomy.INGEST_METRICS but never "
+                "registered" if name in taxonomy.INGEST_METRICS
+                else "not registered in obs/taxonomy.INGEST_METRICS"))
     return problems
 
 

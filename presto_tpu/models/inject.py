@@ -219,7 +219,7 @@ def inject_into_filterbank(inpath: str, outpath: str,
             sigproc.write_filterbank_header(hdr, f)
             for start in range(0, hdr.N, block):
                 n = min(block, hdr.N - start)
-                blk = fb.read_spectra(start, n)
+                blk = fb.read_spectra(start, n, chan_major=False)
                 blk = inject_pulsar(blk, hdr.tsamp, freqs, params,
                                     start_sec=start * hdr.tsamp)
                 if maxval is not None:

@@ -610,6 +610,15 @@ JERK_METRICS = frozenset({
     "accel_wbank_builds_total",
 })
 
+#: the ingest layer's block counters (the SIGPROC decoder's layout and
+#: BlockPrep's clip path): obs-coverage check 22 pins them both
+#: directions against io/ and apps/
+INGEST_METRICS = frozenset({
+    "ingest_decode_blocks_total",
+    "ingest_clip_blocks_total",
+    "ingest_clipped_rows_total",
+})
+
 #: registered metric names (Prometheus side of the contract); the
 #: linter checks every registry.counter/gauge/histogram call in the
 #: tree registers a name listed here.
@@ -647,7 +656,10 @@ METRICS = frozenset({
     "ingest_scrubbed_samples_total",
     "ingest_quarantined_spectra_total",
     "ingest_reports_total",
-    # clipping in apps/common.BlockPrep
+    # the filterbank decode's layout (io/sigproc.decode_spectra_block)
+    # and clipping in apps/common.BlockPrep; pinned both directions by
+    # obs-coverage check 22 via INGEST_METRICS
+    "ingest_decode_blocks_total",
     "ingest_clip_blocks_total",
     "ingest_clipped_rows_total",
     # the banded jerk volume (search/jerk.py); pinned both directions

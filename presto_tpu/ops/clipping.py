@@ -11,6 +11,13 @@ Runs in numpy: it sits in the host read path before data reach the
 device, once per raw block.  clip_times reads the block twice (row
 sums, then the good rows' channel means as a matvec) and, given
 ``out=block``, writes only the clipped rows.
+
+Blocks are [T, C] in either memory layout (the SIGPROC decoder hands
+out channel-major memory); every result keeps the block's layout.
+clip_times' float32 sums are exact for integer samples (8-bit and
+narrower data), so its output does not depend on the layout there;
+remove_zerodm and channel_means accumulate in float64 for the same
+reason.
 """
 
 from __future__ import annotations
@@ -36,7 +43,8 @@ def clip_times(block: np.ndarray, clip_sigma: float,
                ) -> Tuple[np.ndarray, int, ClipState]:
     """Clip RFI-contaminated time samples in one raw block.
 
-    block: [ptsperblk, numchan] float32 (time-major, like the reader).
+    block: [ptsperblk, numchan] float32, in either memory layout (the
+    SIGPROC reader's is channel-major); a copy keeps the layout.
     Samples whose zero-DM (band-summed) value deviates more than
     clip_sigma from the running mean are replaced by the per-channel
     running averages.  Returns (clipped_block, nclipped, new_state).
@@ -92,7 +100,7 @@ def clip_times(block: np.ndarray, clip_sigma: float,
     trigger = clip_sigma * running_std
     bad = np.abs(zero_dm - running_avg) > trigger
     if out is None:
-        out = block.copy()
+        out = block.copy(order="K")
     elif out is not block:
         np.copyto(out, block)
     nbad = int(bad.sum())
@@ -103,6 +111,14 @@ def clip_times(block: np.ndarray, clip_sigma: float,
                           running_std=running_std,
                           blocksread=state.blocksread + 1)
     return out, nbad, new_state
+
+
+def channel_means(block: np.ndarray) -> np.ndarray:
+    """[1, C] float32 per-channel means of a [T, C] block, accumulated
+    in float64: exact for integer samples, and the same for either
+    memory layout."""
+    return block.mean(axis=0, keepdims=True,
+                      dtype=np.float64).astype(np.float32)
 
 
 def remove_zerodm(block: np.ndarray,
@@ -119,14 +135,18 @@ def remove_zerodm(block: np.ndarray,
     padvals for the preferred behavior).
     """
     if bandpass is None or bandpass.sum() <= 0:
-        bandpass = block.mean(axis=0)
+        bandpass = channel_means(block)[0]
     tot = bandpass.sum()
     if tot <= 0:       # all-zero block (e.g. padding): nothing to remove
         return block.astype(np.float32)
     wts = bandpass / tot
-    zerodm = block.sum(axis=1, keepdims=True)        # [T, 1]
-    return (block - wts[None, :] * zerodm
-            + bandpass[None, :]).astype(np.float32)
+    zerodm = block.sum(axis=1, keepdims=True,
+                       dtype=np.float64).astype(np.float32)   # [T, 1]
+    # the broadcast product in the block's layout, so the result keeps it
+    chan_major = block.flags.f_contiguous and not block.flags.c_contiguous
+    removed = np.multiply(wts[None, :], zerodm,
+                          order="F" if chan_major else "C")
+    return (block - removed + bandpass[None, :]).astype(np.float32)
 
 
 def mask_block(block: np.ndarray, maskchans: np.ndarray,
@@ -134,7 +154,7 @@ def mask_block(block: np.ndarray, maskchans: np.ndarray,
     """Replace masked channels with their padding values.
     Parity: the mask substitution in read_psrdata
     (backend_common.c:557-572)."""
-    out = block.copy()
+    out = block.copy(order="K")
     if len(maskchans):
         out[:, maskchans] = padvals[maskchans]
     return out

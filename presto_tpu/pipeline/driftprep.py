@@ -134,11 +134,15 @@ def split_drift_scan(rawfiles: Sequence[str], outdir: str = ".",
     artifact-per-stage checkpoint contract).
     """
     from presto_tpu.apps.common import open_raw
-    from presto_tpu.io.sigproc import FilterbankHeader, \
-        write_filterbank_header, pack_bits
+    from presto_tpu.io.sigproc import FilterbankFile, FilterbankHeader, \
+        FilterbankSet, write_filterbank_header, pack_bits
 
     os.makedirs(outdir, exist_ok=True)
     fb = open_raw(list(rawfiles))
+    # a SIGPROC reader decodes time-major, the order written below, on
+    # request; PSRFITS rows are time-major already
+    layout = ({"chan_major": False}
+              if isinstance(fb, (FilterbankFile, FilterbankSet)) else {})
     try:
         hdr = fb.header
         total = int(fb.nspectra)
@@ -162,7 +166,6 @@ def split_drift_scan(rawfiles: Sequence[str], outdir: str = ".",
                 # reuse only when the existing cut matches THIS plan's
                 # geometry (a rerun with different orig_N/overlap
                 # collides on the name but must not keep stale cuts)
-                from presto_tpu.io.sigproc import FilterbankFile
                 try:
                     with FilterbankFile(path) as old:
                         # same sample count AND same start time: a
@@ -207,7 +210,7 @@ def split_drift_scan(rawfiles: Sequence[str], outdir: str = ".",
                                 p.start_sample + p.nsamp, max_block):
                     cnt = min(max_block,
                               p.start_sample + p.nsamp - s0)
-                    block = fb.read_spectra(s0, cnt)
+                    block = fb.read_spectra(s0, cnt, **layout)
                     if out_hdr.foff < 0:
                         block = block[:, ::-1]
                     if out_hdr.nbits == 32:

@@ -124,7 +124,7 @@ def rfifind_stream(intervals, numchan: int, ptsperint: int, dt: float,
     avgs, stds, pows = [], [], []
     for block in intervals:
         cells = np.ascontiguousarray(
-            block.T).astype(np.float32)          # [numchan, ptsperint]
+            block.T, dtype=np.float32)           # [numchan, ptsperint]
         a, s, p = _interval_stats(jnp.asarray(cells), ptsperint)
         avgs.append(np.asarray(a))
         stds.append(np.asarray(s))

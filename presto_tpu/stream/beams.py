@@ -717,8 +717,10 @@ class BeamMultiplexer:
                 break
             time.sleep(0.005)
 
-        data = np.zeros((len(self.lanes), self.blocklen, nchan),
-                        np.float32)
+        # [beams, blocklen, nchan] in channel-major memory, like each
+        # lane's blocks: the stacked feed's [beams, C, T] view is free
+        data = np.zeros((len(self.lanes), nchan, self.blocklen),
+                        np.float32).transpose(0, 2, 1)
         nreal = [0] * len(self.lanes)
         arrivals = [time.time()] * len(self.lanes)
         synth = [False] * len(self.lanes)

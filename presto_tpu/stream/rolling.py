@@ -113,7 +113,8 @@ class RollingDedisp:
         self.blocks_in = 0
 
     def feed(self, block_tc: np.ndarray) -> Optional[np.ndarray]:
-        """block_tc: [blocklen, nchan] float32 ascending.  Returns the
+        """block_tc: [blocklen, nchan] float32 ascending (channel-major
+        memory uploads without a transposing copy).  Returns the
         next [numdms, blocklen // downsamp] series block, or None
         while the carry is still priming."""
         cur = jnp.asarray(np.ascontiguousarray(block_tc.T))
@@ -135,7 +136,7 @@ class RollingDedisp:
         """The batch loop's two zero flush blocks: drains the carry,
         returning the final series blocks."""
         outs = []
-        zero = np.zeros((blocklen, nchan), np.float32)
+        zero = np.zeros((blocklen, nchan), np.float32, order="F")
         for _ in range(2):
             out = self.feed(zero)
             if out is not None:

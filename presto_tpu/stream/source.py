@@ -56,7 +56,8 @@ class StreamBlock:
     """One ring slot: a fixed-length block of decoded spectra."""
     seq: int                    # block index in the stream (0-based)
     start: int                  # absolute first spectrum index
-    data: np.ndarray            # [blocklen, nchan] float32 ascending
+    data: np.ndarray            # [blocklen, nchan] float32 ascending,
+                                # channel-major memory
     nreal: int                  # spectra actually received (rest pad)
     t_arrival: float            # wall clock when the block completed
     quarantined: List = field(default_factory=list)  # BadInterval-ish
@@ -140,9 +141,11 @@ class RingBlockSource:
             off = 0
             while off < len(arr):
                 if self._partial is None:
+                    # channel-major like the decoder's blocks: the
+                    # dedispersion feed's [C, T] view needs no copy
                     self._partial = np.zeros(
                         (self.blocklen, self.header.nchans),
-                        np.float32)
+                        np.float32, order="F")
                     self._partial_fill = 0
                 take = min(self.blocklen - self._partial_fill,
                            len(arr) - off)
@@ -248,7 +251,8 @@ class RingBlockSource:
                     seq=self._next_seq,
                     start=self._next_seq * self.blocklen,
                     data=np.zeros((self.blocklen,
-                                   self.header.nchans), np.float32),
+                                   self.header.nchans), np.float32,
+                                  order="F"),
                     nreal=0, t_arrival=head.t_arrival,
                     quarantined=[("ring-drop",
                                   self._next_seq * self.blocklen,

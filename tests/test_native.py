@@ -32,17 +32,36 @@ def test_unpack_bits_parity(nbits):
 @pytest.mark.parametrize("nbits", [1, 2, 4, 8])
 @pytest.mark.parametrize("nifs", [1, 2])
 @pytest.mark.parametrize("flip", [False, True])
-def test_decode_spectra_parity(nbits, nifs, flip):
-    nspec, nchan = 17, 32
+def test_decode_spectra_parity(nbits, nifs, flip, monkeypatch):
+    """Native and NumPy-fallback decodes equal the reference values:
+    channel-major memory that owns its data by default, the C-order
+    block with chan_major=False.  The shape spans more than one of the
+    native decoder's tiles, with ragged edges on both axes."""
+    nspec, nchan = 300, 40
     nvals = nspec * nifs * nchan
     raw = RNG.integers(0, 256, size=nvals * nbits // 8).astype(np.uint8)
-    got = native.decode_spectra(raw, nspec, nifs, nchan, nbits, flip)
     vals = sigproc.unpack_bits(raw, nbits)
     want = vals.astype(np.float32).reshape(nspec, nifs, nchan)
     want = want.sum(axis=1) if nifs > 1 else want[:, 0, :]
     if flip:
         want = want[:, ::-1]
-    assert np.array_equal(got, want)
+    hdr = sigproc.FilterbankHeader(nchans=nchan, nifs=nifs, nbits=nbits,
+                                   fch1=1500.0, foff=-1.0 if flip else 1.0)
+    chan = [native.decode_spectra(raw, nspec, nifs, nchan, nbits, flip)]
+    time = [native.decode_spectra(raw, nspec, nifs, nchan, nbits, flip,
+                                  chan_major=False)]
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setenv("PRESTO_TPU_NO_NATIVE", "1")
+    chan.append(sigproc.decode_spectra_block(hdr, raw, nspec))
+    time.append(sigproc.decode_spectra_block(hdr, raw, nspec,
+                                             chan_major=False))
+    for got in chan + time:
+        assert got.dtype == np.float32 and got.flags.owndata
+        assert np.array_equal(got, want)
+    for got in chan:
+        assert got.flags.f_contiguous and not got.flags.c_contiguous
+    for got in time:
+        assert got.flags.c_contiguous and not got.flags.f_contiguous
 
 
 @pytest.mark.parametrize("nbits", [2, 4, 8])
